@@ -470,9 +470,12 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            ).strip()
 import time
 import numpy as np
+import jax
 from repro.distributed import multihost
 assert multihost.initialize(f"127.0.0.1:{port}", num_processes=2,
                             process_id=proc_id)
+platform = jax.devices()[0].platform
+print(f"multihost worker {proc_id}: platform {platform}", flush=True)
 from benchmarks.des_complexity import _alpha_step_instances
 from repro.core import des as des_lib
 
@@ -509,6 +512,7 @@ if proc_id == 0:
         "layers": layers,
         "multihost_ms_total": round(sum(totals) * 1e3, 3),
         "bit_identical": identical,
+        "platform": platform,
     }), flush=True)
 """
 
@@ -523,8 +527,10 @@ def run_multihost_sweep(k: int = 8, n_tokens: int = 256, d: int = 2,
 
     Every process solves its contiguous half of the (K*N) instance batch
     on its local device mesh; results are exchanged through the
-    coordination-service KV store — no cross-process XLA computations,
-    so this runs on the CPU-only CI container too.
+    coordination-service KV store — no cross-process XLA computations.
+    The workers are put on the CPU backend explicitly (and report it):
+    a parent that already ran on an accelerator holds it, and a worker
+    reaching for the same chip would fail or hang.
     """
     import socket
     import subprocess
@@ -535,6 +541,7 @@ def run_multihost_sweep(k: int = 8, n_tokens: int = 256, d: int = 2,
         port = s.getsockname()[1]
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(repo, "src"), repo,
                     env.get("PYTHONPATH", "")) if p)
@@ -568,7 +575,8 @@ def run_multihost_sweep(k: int = 8, n_tokens: int = 256, d: int = 2,
                   f"{row['n_processes']} processes "
                   f"({row['local_rows']} rows/process, "
                   f"identical={row['bit_identical']})")
-        print(f"multihost total: {result['multihost_ms_total']:.1f} ms")
+        print(f"multihost total: {result['multihost_ms_total']:.1f} ms "
+              f"(workers on {result['platform']})")
     return result
 
 

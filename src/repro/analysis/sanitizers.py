@@ -34,7 +34,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-_COMPILE_RE = re.compile(r"Compiling ([A-Za-z0-9_<>.\-]+) (?:with|for)")
+#: JAX >= 0.5 names the jitted function as ``jit(<name>)``.
+_COMPILE_RE = re.compile(
+    r"Compiling (?:jit\()?([A-Za-z0-9_<>.\-]+)\)? (?:with|for)")
 
 #: Loggers that emit the per-compilation record (version-dependent).
 _COMPILE_LOGGERS = ("jax._src.interpreters.pxla", "jax._src.dispatch")

@@ -43,7 +43,7 @@ equality of the *accumulation order*:
     hard rather than replicating numpy's data-dependent compressed sum.
 
 Everything runs in float64 — callers must invoke the jitted function
-under `jax.experimental.enable_x64()` (see `repro.schedulers.sharded`).
+under `jax.enable_x64(True)` (see `repro.schedulers.sharded`).
 """
 
 from __future__ import annotations

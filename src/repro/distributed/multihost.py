@@ -94,29 +94,18 @@ def initialize(coordinator_address: Optional[str] = None,
     return process_count() > 1
 
 
-def _global_state():
-    """The jax distributed-runtime state object (None-client when the
-    runtime was never initialized); tolerant of the private-module move
-    between jax versions."""
-    try:
-        from jax._src import distributed as _dist
-        return _dist.global_state
-    except ImportError:  # pragma: no cover - older/newer layouts
-        import jax
-        return getattr(jax.distributed, "global_state", None)
-
-
 def is_initialized() -> bool:
     """True iff the `jax.distributed` runtime is up in this process."""
-    state = _global_state()
-    return state is not None and state.client is not None
+    import jax
+    return jax.distributed.is_initialized()
 
 
 def coordination_client():
     """The coordination-service client (KV store + barriers), or None in
-    single-process mode."""
-    state = _global_state()
-    return None if state is None else state.client
+    single-process mode.  jax exposes no public handle to it; the
+    runtime's state object lives in `jax._src.distributed`."""
+    from jax._src import distributed as _dist
+    return _dist.global_state.client
 
 
 def process_count() -> int:

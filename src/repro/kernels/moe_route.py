@@ -57,6 +57,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.moe_ffn import row_block, swiglu_block
+
 #: The `MoEConfig.routing_impl` vocabulary: "xla" is the historical
 #: einsum path (byte-for-byte unchanged default), "fused" the capacity-
 #: layout Pallas pipeline, "grouped" the ragged-layout pipeline.
@@ -83,7 +85,9 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """A kernel entry point's ``interpret=``: None means
+    `default_interpret()`, a bool is taken as given."""
     return default_interpret() if interpret is None else bool(interpret)
 
 
@@ -135,7 +139,7 @@ def fused_route(gate_logits: jnp.ndarray,
     `repro.core.selection.route` on the same mask.
     """
     t, e = gate_logits.shape
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     block_t = min(block_t, t)
     pt = (-t) % block_t
     lg = gate_logits
@@ -191,14 +195,48 @@ def capacity_positions(mask: jnp.ndarray, cap: int
 # (b) capacity layout: fused gather-dispatch + weighted combine
 # ----------------------------------------------------------------------
 
-def _dispatch_kernel(x_ref, pos_ref, keep_ref, o_ref):
-    o_ref[...] = jnp.zeros_like(o_ref)
+def _lane_block(d: int, target: int = 1024) -> int:
+    """Hidden-axis block for the dispatch/combine kernels: ``target``
+    lanes where it divides d, else the whole axis (legal either way
+    under the TPU (8, 128) tiling rule)."""
+    return target if d % target == 0 else d
+
+
+def _rows32(a: jnp.ndarray) -> jnp.ndarray:
+    """Widen a sub-32-bit float array to fp32 (exact for bf16/f16).
+    Mosaic indexes single rows dynamically only in 32-bit layouts; a
+    packed bf16 row at a runtime offset is refused."""
+    return a.astype(jnp.float32) if a.dtype.itemsize < 4 else a
+
+
+def _group_table(a: jnp.ndarray, dtype) -> jnp.ndarray:
+    """(G, gsz, E) slot bookkeeping → a (G, 1, gsz*E) table whose
+    per-group block the kernels read one scalar at a time from SMEM;
+    entry (g, s, e) sits at [g, 0, s*E + e].  Vector blocks over an
+    E-wide last axis are not legal TPU tiles, and one group's block
+    keeps SMEM use independent of G."""
+    g, gsz, e = a.shape
+    return a.reshape(g, 1, gsz * e).astype(dtype)
+
+
+def _table_spec(gsz: int, e: int, group_axis: int) -> pl.BlockSpec:
+    return pl.BlockSpec((1, 1, gsz * e),
+                        lambda *ids: (ids[group_axis], 0, 0),
+                        memory_space=pltpu.SMEM)
+
+
+def _dispatch_kernel(pos_ref, keep_ref, x_ref, o_ref, *, num_e: int):
+    ei = pl.program_id(0)
     gsz = x_ref.shape[1]
+    o_ref[...] = jnp.zeros_like(o_ref)
 
     def body(s, carry):
-        @pl.when(keep_ref[0, s, 0] > 0)
+        idx = s * num_e + ei
+
+        @pl.when(keep_ref[0, 0, idx] > 0)
         def _():
-            o_ref[0, 0, pos_ref[0, s, 0]] = x_ref[0, s]
+            o_ref[0, 0, pl.ds(pos_ref[0, 0, idx], 1), :] = (
+                x_ref[0, pl.ds(s, 1), :])
         return carry
 
     jax.lax.fori_loop(0, gsz, body, 0)
@@ -209,49 +247,56 @@ def capacity_dispatch(x: jnp.ndarray, pos: jnp.ndarray, keep: jnp.ndarray,
                       ) -> jnp.ndarray:
     """Gather-dispatch (G, gsz, d) → (E, G, cap, d) without the one-hot.
 
-    Each (expert, group) program walks its group's tokens once, writing
-    kept rows straight into their capacity slot — HBM traffic is
-    O(E·G·cap·d) instead of the einsum's O(G·gsz·E·cap) one-hot.
+    Each (expert, group, hidden-block) program walks its group's tokens
+    once, writing kept rows straight into their capacity slot — HBM
+    traffic is O(E·G·cap·d) instead of the einsum's O(G·gsz·E·cap)
+    one-hot.  The slot tables ride in SMEM; rows move as 32-bit words
+    (`_rows32`), so the copy stays bit-exact.
     """
     g, gsz, d = x.shape
     e = pos.shape[-1]
-    interpret = _resolve_interpret(interpret)
-    return pl.pallas_call(
-        _dispatch_kernel,
-        grid=(e, g),
+    bd = _lane_block(d)
+    interpret = resolve_interpret(interpret)
+    x32 = _rows32(x)
+    out = pl.pallas_call(
+        functools.partial(_dispatch_kernel, num_e=e),
+        grid=(e, g, d // bd),
         in_specs=[
-            pl.BlockSpec((1, gsz, d), lambda ei, gi: (gi, 0, 0)),
-            pl.BlockSpec((1, gsz, 1), lambda ei, gi: (gi, 0, ei)),
-            pl.BlockSpec((1, gsz, 1), lambda ei, gi: (gi, 0, ei)),
+            _table_spec(gsz, e, 1),
+            _table_spec(gsz, e, 1),
+            pl.BlockSpec((1, gsz, bd), lambda ei, gi, di: (gi, 0, di)),
         ],
-        out_specs=pl.BlockSpec((1, 1, cap, d),
-                               lambda ei, gi: (ei, gi, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((e, g, cap, d), x.dtype),
+        out_specs=pl.BlockSpec((1, 1, cap, bd),
+                               lambda ei, gi, di: (ei, gi, 0, di)),
+        out_shape=jax.ShapeDtypeStruct((e, g, cap, d), x32.dtype),
         interpret=interpret,
-    )(x, pos, keep)
+    )(_group_table(pos, jnp.int32), _group_table(keep > 0, jnp.int32), x32)
+    return out.astype(x.dtype)
 
 
-def _combine_kernel(ye_ref, cw_ref, pos_ref, keep_ref, o_ref, acc_scr, *,
+def _combine_kernel(pos_ref, keep_ref, cw_ref, ye_ref, o_ref, acc_scr, *,
                     num_e: int):
-    ei = pl.program_id(1)
+    ei = pl.program_id(2)
 
     @pl.when(ei == 0)
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    gsz = cw_ref.shape[1]
+    gsz = acc_scr.shape[0]
 
     def body(s, carry):
-        @pl.when(keep_ref[0, s, 0] > 0)
+        idx = s * num_e + ei
+
+        @pl.when(keep_ref[0, 0, idx] > 0)
         def _():
             # bare multiply feeding the accumulate: XLA contracts the
-            # pair into an FMA; `grouped_scatter` keeps the identical
-            # mul→add structure so both layouts contract the same way
+            # pair into an FMA; `grouped_scatter` goes through this same
+            # kernel, so both layouts contract the same way
             # (bit-equality contract — a `where`/barrier between the
             # two ops would block contraction on one side only).
-            acc_scr[s] += (cw_ref[0, s, 0]
-                           * ye_ref[0, 0, pos_ref[0, s, 0]].astype(
-                               jnp.float32))
+            acc_scr[pl.ds(s, 1), :] += (
+                cw_ref[0, 0, idx]
+                * ye_ref[0, 0, pl.ds(pos_ref[0, 0, idx], 1), :])
         return carry
 
     jax.lax.fori_loop(0, gsz, body, 0)
@@ -269,27 +314,28 @@ def capacity_combine(ye: jnp.ndarray, cw: jnp.ndarray, pos: jnp.ndarray,
     Accumulates each token's selected-expert contributions in an fp32
     scratch, expert ids ascending (the grid's inner axis) — the
     accumulation order the grouped layout's scatter-back replays for
-    bit-equality.
+    bit-equality.  Slot tables and combine weights ride in SMEM.
     """
     e, g, cap, d = ye.shape
     gsz = cw.shape[1]
-    interpret = _resolve_interpret(interpret)
+    bd = _lane_block(d)
+    interpret = resolve_interpret(interpret)
     out_dtype = out_dtype or ye.dtype
-    kernel = functools.partial(_combine_kernel, num_e=e)
     return pl.pallas_call(
-        kernel,
-        grid=(g, e),
+        functools.partial(_combine_kernel, num_e=e),
+        grid=(g, d // bd, e),
         in_specs=[
-            pl.BlockSpec((1, 1, cap, d), lambda gi, ei: (ei, gi, 0, 0)),
-            pl.BlockSpec((1, gsz, 1), lambda gi, ei: (gi, 0, ei)),
-            pl.BlockSpec((1, gsz, 1), lambda gi, ei: (gi, 0, ei)),
-            pl.BlockSpec((1, gsz, 1), lambda gi, ei: (gi, 0, ei)),
+            _table_spec(gsz, e, 0),
+            _table_spec(gsz, e, 0),
+            _table_spec(gsz, e, 0),
+            pl.BlockSpec((1, 1, cap, bd), lambda gi, di, ei: (ei, gi, 0, di)),
         ],
-        out_specs=pl.BlockSpec((1, gsz, d), lambda gi, ei: (gi, 0, 0)),
+        out_specs=pl.BlockSpec((1, gsz, bd), lambda gi, di, ei: (gi, 0, di)),
         out_shape=jax.ShapeDtypeStruct((g, gsz, d), out_dtype),
-        scratch_shapes=[pltpu.VMEM((gsz, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((gsz, bd), jnp.float32)],
         interpret=interpret,
-    )(ye, cw, pos, keep)
+    )(_group_table(pos, jnp.int32), _group_table(keep > 0, jnp.int32),
+      _group_table(cw, jnp.float32), _rows32(ye))
 
 
 # ----------------------------------------------------------------------
@@ -333,7 +379,7 @@ def grouped_layout(pos: jnp.ndarray, keep: jnp.ndarray, cap: int,
     """
     g, gsz, e = pos.shape
     seg = g * cap                      # worst-case kept rows per expert
-    block_c = min(block_c, seg)
+    block_c = row_block(seg, block_c)
     seg_pad = seg + ((-seg) % block_c)
     total = e * seg_pad + block_c      # + trailing scratch block
     # kept (token, expert) pair → expert-major row: e·seg_pad + g·cap + slot
@@ -381,18 +427,8 @@ def _ragged_ffn_kernel(be_ref, act_ref, x_ref, w1_ref, wu_ref, w2_ref,
 
     @pl.when(act_ref[bi] > 0)
     def _compute():
-        x = x_ref[...].astype(jnp.float32)             # (Bc, d)
-        w1 = w1_ref[0].astype(jnp.float32)             # (d, Bf)
-        wu = wu_ref[0].astype(jnp.float32)
-        w2 = w2_ref[0].astype(jnp.float32)             # (Bf, d)
-        g = jax.lax.dot_general(x, w1, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        u = jax.lax.dot_general(x, wu, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        h = jax.nn.silu(g) * u
-        acc_scr[...] += jax.lax.dot_general(
-            h, w2, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_scr[...] += swiglu_block(x_ref[...], w1_ref[0], wu_ref[0],
+                                     w2_ref[0])
 
     @pl.when(fi == num_f_blocks - 1)
     def _finalize():
@@ -401,7 +437,7 @@ def _ragged_ffn_kernel(be_ref, act_ref, x_ref, w1_ref, wu_ref, w2_ref,
 
 def moe_expert_ffn_ragged(xs: jnp.ndarray, layout: GroupedLayout,
                           w1: jnp.ndarray, w_up: jnp.ndarray,
-                          w2: jnp.ndarray, *, block_f: int = 512,
+                          w2: jnp.ndarray, *, block_f: int = 128,
                           interpret: Optional[bool] = None) -> jnp.ndarray:
     """Ragged SwiGLU expert FFN over the grouped layout.
 
@@ -417,7 +453,7 @@ def moe_expert_ffn_ragged(xs: jnp.ndarray, layout: GroupedLayout,
     total, d = xs.shape
     f = w1.shape[-1]
     block_c = layout.block_c
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     block_f = min(block_f, f)
     pf = (-f) % block_f
     if pf:

@@ -224,7 +224,9 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
             hlo = compiled.as_text()
         roof = rl.analyze(
             arch=arch, shape=shape_name, mesh_name=mesh_name,
-            n_devices=mesh.size, cost=cost, memstats=memstats,
+            n_devices=mesh.size,
+            device_kind=mesh_lib.PRODUCTION_DEVICE_KIND, cost=cost,
+            memstats=memstats,
             hlo_text=hlo, model_flops=mf)
         from repro.launch import hlo_cost
         hc = hlo_cost.analyze_hlo(hlo)
@@ -247,7 +249,8 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
                     memstats, "output_size_in_bytes", 0) or 0),
             },
             "fits_hbm": (roof.arg_bytes + roof.temp_bytes)
-            <= mesh_lib.HBM_PER_CHIP,
+            <= mesh_lib.chip_peaks(
+                mesh_lib.PRODUCTION_DEVICE_KIND).hbm_bytes,
         }
         if verbose:
             print(f"[OK] {arch} x {shape_name} @ {mesh_name}: "

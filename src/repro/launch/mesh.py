@@ -10,6 +10,8 @@ state (the dry-run launcher must set XLA_FLAGS before any device query).
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 
 
@@ -24,8 +26,34 @@ def make_host_mesh():
     return jax.make_mesh((1, 1), ("data", "model"))
 
 
-# Hardware constants for the roofline (TPU v5e per chip)
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # bytes/s
-ICI_BW_PER_LINK = 50e9          # bytes/s/link (~ per-direction)
-HBM_PER_CHIP = 16e9             # bytes
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator kind."""
+
+    flops_bf16: float          # FLOP/s
+    hbm_bw: float              # bytes/s
+    ici_bw_per_link: float     # bytes/s per link, one direction
+    hbm_bytes: float           # bytes
+
+
+#: Peaks keyed by `jax.Device.device_kind`.  Source: Google Cloud
+#: documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s,
+#: 1,600 Gbit/s of inter-chip interconnect (four links of 50 GB/s).
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops_bf16=197e12, hbm_bw=819e9,
+                             ici_bw_per_link=50e9, hbm_bytes=16e9),
+}
+
+#: The chip the production meshes above model (a v5e pod).
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The peak table entry for `device_kind`; an unknown kind is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak rates recorded for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}") from None

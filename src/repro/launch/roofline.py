@@ -1,6 +1,7 @@
 """Roofline analysis from compiled dry-run artifacts (no real hardware).
 
-Three terms, all in seconds, per device:
+Three terms, all in seconds, per device of kind `device_kind` (peaks
+from `repro.launch.mesh.PEAKS`):
 
     compute    = HLO_FLOPs / peak_FLOP/s          (cost_analysis is
                                                    already per-partition)
@@ -115,7 +116,8 @@ class Roofline:
 
 def analyze(
     *, arch: str, shape: str, mesh_name: str, n_devices: int,
-    cost: Dict, memstats, hlo_text: str, model_flops: float,
+    device_kind: str, cost: Dict, memstats, hlo_text: str,
+    model_flops: float,
 ) -> Roofline:
     # trip-count-aware re-analysis: XLA's cost_analysis counts while-loop
     # (lax.scan) bodies once, grossly under-reporting scanned-layer
@@ -126,9 +128,10 @@ def analyze(
     flops = float(hc.flops)
     byts = float(hc.bytes_accessed)
     coll = float(hc.collective_bytes)
-    compute_s = flops / mesh_lib.PEAK_FLOPS_BF16
-    memory_s = byts / mesh_lib.HBM_BW
-    coll_s = coll / mesh_lib.ICI_BW_PER_LINK
+    peaks = mesh_lib.chip_peaks(device_kind)
+    compute_s = flops / peaks.flops_bf16
+    memory_s = byts / peaks.hbm_bw
+    coll_s = coll / peaks.ici_bw_per_link
     terms = {"compute": compute_s, "memory": memory_s, "collective": coll_s}
     bottleneck = max(terms, key=terms.get)
     total_hlo_flops = flops * n_devices

@@ -15,6 +15,7 @@ import argparse
 import numpy as np
 
 from repro.configs.base import get_config, get_smoke_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.schedulers import available_policies
 from repro.serving import DMoESimulator, Request, ServingEngine
 
@@ -35,6 +36,7 @@ def main():
     ap.add_argument("--tokens-per-query", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     rng = np.random.default_rng(args.seed)

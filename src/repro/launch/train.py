@@ -22,6 +22,7 @@ from repro.configs.base import (get_config, get_smoke_config,
                                 resolve_routing_policy)
 from repro.data import DataConfig, lm_batch
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as model_lib
 from repro.optim import AdamWConfig, init_opt_state
 from repro import checkpoint as ckpt_lib
@@ -98,6 +99,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
     _, history = train(
         args.arch, smoke=args.smoke, steps=args.steps, batch=args.batch,
         seq=args.seq, lr=args.lr, routing=args.routing,

@@ -127,6 +127,12 @@ def _drop_aux(mk, keep):
             "dropped_tokens": jnp.sum(mk) - jnp.sum(keep)}
 
 
+def _combine_weights(cw, keep, act_dtype):
+    """Kept combine weights, rounded to the activation dtype as the XLA
+    path's combine tensor is (the kernels widen them back exactly)."""
+    return (cw * keep).astype(act_dtype)
+
+
 def _dispatch_ffn_fused(params, xg, mk, cw, cap, act_dtype):
     """`routing_impl="fused"`: Pallas gather-dispatch straight into the
     (E, G, cap, d) capacity layout + fused SwiGLU FFN + weighted combine
@@ -135,7 +141,7 @@ def _dispatch_ffn_fused(params, xg, mk, cw, cap, act_dtype):
     e = mk.shape[-1]
     pos, keep = mr.capacity_positions(mk, cap)
     aux = _drop_aux(mk, keep)
-    cwk = cw * keep
+    cwk = _combine_weights(cw, keep, act_dtype)
     xe = mr.capacity_dispatch(xg, pos, keep, cap)        # (E, G, cap, d)
     ye = kops.moe_expert_ffn(xe.reshape(e, g * cap, d), params["w1"],
                              params["wu"], params["w2"])
@@ -151,7 +157,7 @@ def _dispatch_ffn_grouped(params, xg, mk, cw, cap, act_dtype):
     dense capacity grid when token→expert loads are skewed."""
     pos, keep = mr.capacity_positions(mk, cap)
     aux = _drop_aux(mk, keep)
-    cwk = cw * keep
+    cwk = _combine_weights(cw, keep, act_dtype)
     layout = mr.grouped_layout(pos, keep, cap)
     xs = mr.grouped_dispatch(xg, layout)                 # (total, d)
     ys = mr.moe_expert_ffn_ragged(xs, layout, params["w1"],

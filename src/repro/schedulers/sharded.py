@@ -74,9 +74,8 @@ def _sharded_prework_fn(mesh, max_experts: int):
 
     Traced under x64 so every comparison happens in float64, matching the
     numpy solver bit-for-bit.  Callers must invoke the returned function
-    under `jax.experimental.enable_x64()` as well (same trace avals)."""
+    under `jax.enable_x64(True)` as well (same trace avals)."""
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core import des_prework as des_prework_lib
@@ -95,8 +94,8 @@ def _sharded_prework_fn(mesh, max_experts: int):
         return des_prework_lib.prework(scores, costs, qos, forced,
                                        max_experts=max_experts)
 
-    fn = shard_map(des_prework, mesh=mesh,
-                   in_specs=(mat, mat, row, mat), out_specs=out_specs)
+    fn = jax.shard_map(des_prework, mesh=mesh,
+                       in_specs=(mat, mat, row, mat), out_specs=out_specs)
     return jax.jit(fn)
 
 
@@ -145,7 +144,7 @@ def submit_prework(
         mesh = _default_mesh()
     out = None
     if b:
-        from jax.experimental import enable_x64
+        import jax
 
         from repro.distributed.sharding import pad_to_devices
 
@@ -158,7 +157,7 @@ def submit_prework(
             zp = np.concatenate([z, np.zeros(pad)])
             fp = np.vstack([forced, np.zeros((pad, k), dtype=bool)])
         fn = _sharded_prework_fn(mesh, d)
-        with enable_x64():
+        with jax.enable_x64(True):
             out = fn(tp, ep, zp, fp)
     return PreworkHandle(t, e_raw, z, forced, d, mesh, out)
 

@@ -112,7 +112,14 @@ class ServingEngine:
         stats.wall_s = time.time() - t0
         return stats
 
-    def _serve_batch(self, reqs: List[Request], stats: EngineStats):
+    def prefill(self, reqs: List[Request]):
+        """Run the jitted prefill on `reqs`.  Returns (last-position
+        logits (B, V), caches)."""
+        return self._prefill(self.params, *self.prefill_inputs(reqs))
+
+    def prefill_inputs(self, reqs: List[Request]):
+        """The (batch, caches) the jitted prefill takes for `reqs`: the
+        prompts left-padded into one batch, and fresh caches."""
         b = len(reqs)
         plen = max(len(r.prompt) for r in reqs)
         toks = np.zeros((b, plen), dtype=np.int32)
@@ -123,9 +130,13 @@ class ServingEngine:
         if self.cfg.enc_dec:
             batch["frames"] = jnp.zeros(
                 (b, self.cfg.encoder_max_len, self.cfg.d_model))
+        return batch, caches
+
+    def _serve_batch(self, reqs: List[Request], stats: EngineStats):
+        b = len(reqs)
         t_start = time.time()
-        logits, caches = self._prefill(self.params, batch, caches)
-        stats.prefill_tokens += b * plen
+        logits, caches = self.prefill(reqs)
+        stats.prefill_tokens += b * max(len(r.prompt) for r in reqs)
 
         n_steps = max(r.max_new_tokens for r in reqs)
         out = np.zeros((b, n_steps), dtype=np.int32)

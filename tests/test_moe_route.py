@@ -20,6 +20,8 @@ reference — the pre-existing one-hot einsum pipeline in
     interpret mode and the per-call knob stays overridable.
 """
 
+import inspect
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -357,6 +359,24 @@ def test_default_interpret_cpu():
     interpret=True, now it resolves via `default_interpret()`."""
     assert jax.default_backend() != "tpu"
     assert mr.default_interpret() is True
+
+
+@pytest.mark.parametrize("backend, expected",
+                         [("cpu", True), ("gpu", True), ("tpu", False)])
+def test_resolve_interpret_follows_backend(monkeypatch, backend, expected):
+    """None lowers through Mosaic on a TPU only; an explicit bool wins."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert mr.resolve_interpret(None) is expected
+    assert mr.resolve_interpret(not expected) is (not expected)
+
+
+@pytest.mark.parametrize("name", ["flash_attention", "flash_decode",
+                                  "wkv_chunked", "moe_expert_ffn",
+                                  "fused_route"])
+def test_ops_wrappers_default_to_backend_detection(name):
+    """No wrapper forces interpret mode on a TPU caller."""
+    sig = inspect.signature(getattr(ops, name))
+    assert sig.parameters["interpret"].default is None
 
 
 def test_interpret_knob_overridable():
