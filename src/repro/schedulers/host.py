@@ -13,6 +13,7 @@ from __future__ import annotations
 from typing import List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import channel as channel_lib
 from repro.core import des as des_lib
@@ -38,10 +39,11 @@ def _round_energy(alpha: np.ndarray, beta: np.ndarray, ctx: ScheduleContext
 def _allocate_beta(alpha: np.ndarray, ctx: ScheduleContext,
                    beta_method: str) -> np.ndarray:
     """Optimal subcarrier assignment for the traffic implied by alpha."""
-    s_bytes = ctx.s0 * alpha.sum(axis=1).astype(np.float64)
-    np.fill_diagonal(s_bytes, 0.0)  # in-situ: no transmission
-    return sc_lib.allocate_subcarriers(s_bytes, ctx.rates, ctx.p0,
-                                       method=beta_method)
+    with TraceAnnotation("dmoe.assign"):
+        s_bytes = ctx.s0 * alpha.sum(axis=1).astype(np.float64)
+        np.fill_diagonal(s_bytes, 0.0)  # in-situ: no transmission
+        return sc_lib.allocate_subcarriers(s_bytes, ctx.rates, ctx.p0,
+                                           method=beta_method)
 
 
 def _des_sweep(gate_scores: np.ndarray, costs: np.ndarray, qos: float,
@@ -61,25 +63,34 @@ def _des_sweep(gate_scores: np.ndarray, costs: np.ndarray, qos: float,
     z*gamma^(l) annealing schedule, across BCD iterations, and across
     protocol rounds.  Cached answers stay bit-identical to the cold
     sweep; only node counts shrink.  Passed as a kwarg only when set, so
-    drop-in solvers without the parameter keep working cold."""
+    drop-in solvers without the parameter keep working cold.
+
+    The solver call is the `dmoe.des` profiler span, whose metadata
+    `nodes` (the count returned) and `fallback` (rows the solver marked
+    infeasible: Remark-2 Top-D rows) it sets when it ends."""
     if solver is None:
         solver = des_lib.des_select_batch
     kwargs = {} if warm_cache is None else {"warm_cache": warm_cache}
     k, n_tok, n_exp = gate_scores.shape
     flat = np.asarray(gate_scores, dtype=np.float64).reshape(k * n_tok, n_exp)
     active = flat.sum(axis=1) > 0  # padding tokens are never scheduled
-    cost_rows = np.repeat(np.asarray(costs, dtype=np.float64), n_tok, axis=0)
-    if active.all():
-        res = solver(flat, cost_rows, qos, max_experts, **kwargs)
-        alpha = res.selected.astype(np.int8)
-    elif active.any():
-        res = solver(flat[active], cost_rows[active], qos, max_experts,
-                     **kwargs)
-        alpha = np.zeros((k * n_tok, n_exp), dtype=np.int8)
-        alpha[active] = res.selected.astype(np.int8)
-    else:
+    if not active.any():
         return np.zeros_like(gate_scores, dtype=np.int8), 0
-    return alpha.reshape(gate_scores.shape), int(res.nodes_explored.sum())
+    cost_rows = np.repeat(np.asarray(costs, dtype=np.float64), n_tok, axis=0)
+    with TraceAnnotation("dmoe.des") as span:
+        if active.all():
+            res = solver(flat, cost_rows, qos, max_experts, **kwargs)
+            alpha = res.selected.astype(np.int8)
+        else:
+            res = solver(flat[active], cost_rows[active], qos, max_experts,
+                         **kwargs)
+            alpha = np.zeros((k * n_tok, n_exp), dtype=np.int8)
+            alpha[active] = res.selected.astype(np.int8)
+        nodes = int(res.nodes_explored.sum())
+        span.set_metadata(nodes=nodes,
+                          fallback=res.feasible.size
+                          - int(np.count_nonzero(res.feasible)))
+    return alpha.reshape(gate_scores.shape), nodes
 
 
 def best_subcarrier_beta(rates: np.ndarray) -> np.ndarray:

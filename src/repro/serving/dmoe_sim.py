@@ -36,6 +36,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core import channel as channel_lib
@@ -119,6 +120,8 @@ class DMoESimulator:
         # host scheduler each round (see module docstring); disable to
         # serialize device and host work (e.g. for profiling them apart).
         self.overlap = overlap
+        #: served passes so far; the `pass` id of each pass's spans
+        self.passes = 0
 
     # ------------------------------------------------------------------
     def _layer_params(self, layer: int):
@@ -166,27 +169,64 @@ class DMoESimulator:
 
     # ------------------------------------------------------------------
     def serve(self, tokens: np.ndarray) -> SimResult:
-        """tokens: (K, N) — one query of N tokens per expert node."""
+        """tokens: (K, N) — one query of N tokens per expert node.
+
+        Each pass is one `dmoe.pass` profiler span, with `dmoe.*` spans
+        nested at every layer boundary (docs/serving.md, "Tracing a
+        served pass"); they record only while a profiler session runs."""
         cfg = self.cfg
         k, n = tokens.shape
         assert k == self.k, "one query per expert node (§III-C step 1)"
+        self.passes += 1
+        with TraceAnnotation("dmoe.pass", **{"pass": self.passes}):
+            gains = (self.channel_process.step(self.rng)
+                     if self.channel_process is not None else
+                     channel_lib.sample_channel_gains(self.channel_cfg,
+                                                      self.rng))
+            rates = channel_lib.subcarrier_rates(self.channel_cfg, gains)
 
-        gains = (self.channel_process.step(self.rng)
-                 if self.channel_process is not None else
-                 channel_lib.sample_channel_gains(self.channel_cfg,
-                                                  self.rng))
-        rates = channel_lib.subcarrier_rates(self.channel_cfg, gains)
+            with TraceAnnotation("dmoe.embed"):
+                x = jnp.take(self.params["embed"], jnp.asarray(tokens),
+                             axis=0)
+                x = x.astype(jnp.float32 if cfg.dtype == "float32"
+                             else jnp.bfloat16)
 
-        x = jnp.take(self.params["embed"], jnp.asarray(tokens), axis=0)
-        x = x.astype(jnp.float32 if cfg.dtype == "float32" else jnp.bfloat16)
+            rounds: List[proto.RoundAccounting] = []
+            schedules: List[RoundSchedule] = []
+            hist = np.zeros((cfg.num_layers, self.k))
+            for layer in range(cfg.num_layers):
+                with TraceAnnotation("dmoe.round", layer=layer + 1,
+                                     **{"pass": self.passes}):
+                    x, rs, acct = self._round(x, rates, layer)
+                    schedules.append(rs)
+                    rounds.append(acct)
+                    hist[layer] = rs.alpha.sum(axis=(0, 1)) / max(
+                        rs.alpha.sum(), 1)
 
-        rounds: List[proto.RoundAccounting] = []
-        schedules: List[RoundSchedule] = []
-        hist = np.zeros((cfg.num_layers, self.k))
+            with TraceAnnotation("dmoe.unembed"):
+                x = L.rmsnorm(x, self.params["final_norm"], cfg.norm_eps)
+                table = (self.params["embed"] if cfg.tie_embeddings
+                         else self.params["unembed"])
+                logits = L.unembed(x, table)
+            summary = proto.summarize(rounds)
+            with TraceAnnotation("dmoe.logits_d2h"):
+                return SimResult(
+                    logits=np.asarray(logits, dtype=np.float32),
+                    rounds=rounds,
+                    summary=summary,
+                    selection_hist=hist,
+                    schedules=schedules,
+                )
 
-        for layer in range(cfg.num_layers):
+    def _round(self, x, rates: np.ndarray, layer: int):
+        """One protocol round (steps 2-5) on the hidden states x:
+        returns (x after the Eq.-8 combine, the round's schedule, its
+        energy accounting)."""
+        cfg = self.cfg
+        with TraceAnnotation("dmoe.params"):
             p = self._layer_params(layer)
-            # -- step 2: attention + gate (in-situ) --------------------
+        # -- step 2: attention + gate (in-situ) ------------------------
+        with TraceAnnotation("dmoe.attn_gate"):
             h = L.rmsnorm(x, p["norm1"], cfg.norm_eps)
             a, _ = A.gqa_prefill(p["attn"], h, cfg, causal=True)
             x = x + a
@@ -195,22 +235,25 @@ class DMoESimulator:
                                 p["ffn"]["w_gate_router"])
             gates_dev = jax.nn.softmax(logits, axis=-1)   # (K, N, E)
 
-            # -- step 3: joint expert & subcarrier allocation ----------
-            # The per-expert FFN outputs don't depend on alpha (selection
-            # only weights the Eq.-8 combine), so the overlap-aware loop
-            # dispatches them BEFORE blocking on the host scheduler: the
-            # device einsums run concurrently with the host B&B.
-            if self.overlap:
+        # -- step 3: joint expert & subcarrier allocation --------------
+        # The per-expert FFN outputs don't depend on alpha (selection
+        # only weights the Eq.-8 combine), so the overlap-aware loop
+        # dispatches them BEFORE blocking on the host scheduler: the
+        # device einsums run concurrently with the host B&B.
+        if self.overlap:
+            with TraceAnnotation("dmoe.expert_ffn"):
                 ye = self._expert_ffn(h, p)
+        with TraceAnnotation("dmoe.gate_d2h"):
             gates = np.asarray(gates_dev, dtype=np.float64)
+        with TraceAnnotation("dmoe.schedule"):
             rs = self._schedule(gates, rates, layer)
-            if not self.overlap:
+        if not self.overlap:
+            with TraceAnnotation("dmoe.expert_ffn"):
                 ye = self._expert_ffn(h, p)
-            alpha, beta = rs.alpha, rs.beta
-            schedules.append(rs)
-            hist[layer] = alpha.sum(axis=(0, 1)) / max(alpha.sum(), 1)
+        alpha, beta = rs.alpha, rs.beta
 
-            # -- steps 4-5: forward tx + FFN + backward tx + aggregate -
+        # -- steps 4-5: forward tx + FFN + backward tx + aggregate -----
+        with TraceAnnotation("dmoe.combine"):
             am = jnp.asarray(alpha, dtype=jnp.float32)    # (K, N, E)
             w = am * jnp.asarray(gates, dtype=jnp.float32)
             w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)  # Eq. 8
@@ -218,19 +261,9 @@ class DMoESimulator:
                            w).astype(x.dtype)
             x = x + y
 
-            rounds.append(proto.account_round(
+        with TraceAnnotation("dmoe.account"):
+            acct = proto.account_round(
                 layer + 1, alpha, beta, rates, self.comp_coeff, self.s0,
                 self.channel_cfg.tx_power_w,
-                count_backward=self.count_backward))
-
-        x = L.rmsnorm(x, self.params["final_norm"], cfg.norm_eps)
-        table = (self.params["embed"] if cfg.tie_embeddings
-                 else self.params["unembed"])
-        logits = L.unembed(x, table)
-        return SimResult(
-            logits=np.asarray(logits, dtype=np.float32),
-            rounds=rounds,
-            summary=proto.summarize(rounds),
-            selection_hist=hist,
-            schedules=schedules,
-        )
+                count_backward=self.count_backward)
+        return x, rs, acct
