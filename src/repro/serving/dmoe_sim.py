@@ -18,6 +18,16 @@ The model math is exact (the simulator produces the same logits a
 centralized run with the same per-token expert masks would); what is
 simulated is the wireless channel + energy, not the transformer.
 
+Compiled round steps: the device work of a round is three jitted steps,
+built once per simulator -- attention + gate, every expert's FFN, and
+the Eq.-8 combine -- with embed and unembed jitted beside them.  Each
+step slices its layer's weights from the stacked params by a traced
+layer index, so one executable serves every layer, and jit's own cache
+keys them on the (K, N) shape.  The gate step and the FFN step are
+separate executables on purpose: an executable's outputs are ready
+only when all of it ends, so a fused step would hold the gate scores
+back until the FFN were done.
+
 Overlap-aware round loop: the expert FFN einsums are dense in the expert
 axis and independent of the selection alpha (alpha only weights the
 Eq.-8 combine), so with ``overlap=True`` (the default) they are
@@ -31,6 +41,7 @@ accounting, and schedules are unchanged bit for bit.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -48,6 +59,14 @@ from repro.models import layers as L
 from repro.models import model as model_lib
 from repro.schedulers import RoundSchedule, ScheduleContext, SchedulerPolicy
 from repro.schedulers import get_policy
+
+
+def _layer_slice(stack, layer):
+    """One layer's weights from the stacked stage params; `layer` is a
+    traced index, so the slice is made inside the step that reads it."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False),
+        stack)
 
 
 @dataclasses.dataclass
@@ -82,21 +101,15 @@ class DMoESimulator:
         assert not cfg.mla, "simulator uses the plain GQA MoE block"
         self.cfg = cfg
         self.k = cfg.moe.num_experts
-        # Expert-FFN compute backend: "xla" keeps the historical dense
-        # einsums bit for bit; "fused" routes the same dense all-expert
-        # compute through the Pallas `repro.kernels.ops.moe_expert_ffn`
-        # kernel.  "grouped" is rejected — the protocol computes every
-        # expert's FFN for every token (the alpha-independent overlap
-        # trick above), so there is no ragged token→expert assignment to
-        # lay out.
-        if routing_impl not in ("xla", "fused"):
-            from repro.kernels.moe_route import check_routing_impl
-            check_routing_impl(routing_impl)   # unknown name → ValueError
-            raise ValueError(
-                "DMoESimulator computes the dense all-expert FFN (alpha-"
-                "independent overlap); routing_impl must be 'xla' or "
-                f"'fused', got {routing_impl!r}")
-        self.routing_impl = routing_impl
+        #: traces of the jitted steps so far: a Python side effect of
+        #: each step's body, so it counts once per new shape (or new
+        #: backend), never per call
+        self.compiles = 0
+        self._embed = self._jit(self._embed_step)
+        self._attn_gate = self._jit(self._attn_gate_step)
+        self._combine = self._jit(self._combine_step)
+        self._unembed = self._jit(self._unembed_step)
+        self.routing_impl = routing_impl     # validates; builds the FFN step
         # `scheme` is any registry name; a pre-constructed policy instance
         # (with custom kwargs) may be passed directly instead.
         self.policy = policy if policy is not None else get_policy(scheme)
@@ -124,9 +137,72 @@ class DMoESimulator:
         self.passes = 0
 
     # ------------------------------------------------------------------
-    def _layer_params(self, layer: int):
-        stack = self.params["stages"]["stage0"]
-        return jax.tree.map(lambda a: a[layer], stack)
+    @property
+    def routing_impl(self) -> str:
+        """Expert-FFN compute backend: "xla" keeps the historical dense
+        einsums bit for bit; "fused" routes the same dense all-expert
+        compute through the Pallas `repro.kernels.ops.moe_expert_ffn`
+        kernel.  "grouped" is rejected — the protocol computes every
+        expert's FFN for every token (the alpha-independent overlap
+        trick above), so there is no ragged token→expert assignment to
+        lay out.  Settable after construction (`ServingFrontend` does)."""
+        return self._routing_impl
+
+    @routing_impl.setter
+    def routing_impl(self, impl: str) -> None:
+        if impl not in ("xla", "fused"):
+            from repro.kernels.moe_route import check_routing_impl
+            check_routing_impl(impl)   # unknown name → ValueError
+            raise ValueError(
+                "DMoESimulator computes the dense all-expert FFN (alpha-"
+                "independent overlap); routing_impl must be 'xla' or "
+                f"'fused', got {impl!r}")
+        self._routing_impl = impl
+        # A fresh jit: what the old one traced ran the other backend.
+        self._ffn = self._jit(self._ffn_step)
+
+    def _jit(self, step):
+        """`jax.jit` of a step that counts its own traces in `compiles`."""
+        @functools.wraps(step)
+        def traced(*args):
+            self.compiles += 1          # runs only while jax traces
+            return step(*args)
+        return jax.jit(traced)
+
+    # -- the jitted steps ------------------------------------------------
+    def _embed_step(self, table, tokens):
+        x = jnp.take(table, tokens, axis=0)
+        return x.astype(jnp.float32 if self.cfg.dtype == "float32"
+                        else jnp.bfloat16)
+
+    def _attn_gate_step(self, stack, layer, x):
+        """Step 2 (in-situ): the layer's attention and gate.  Returns x
+        after attention, the FFN's input h and the gate scores
+        (K, N, E)."""
+        cfg = self.cfg
+        p = _layer_slice(stack, layer)
+        h = L.rmsnorm(x, p["norm1"], cfg.norm_eps)
+        a, _ = A.gqa_prefill(p["attn"], h, cfg, causal=True)
+        x = x + a
+        h = L.rmsnorm(x, p["norm2"], cfg.norm_eps)
+        logits = jnp.einsum("bsd,de->bse", h.astype(jnp.float32),
+                            p["ffn"]["w_gate_router"])
+        return x, h, jax.nn.softmax(logits, axis=-1)
+
+    def _ffn_step(self, stack, layer, h):
+        return self._expert_ffn(h, _layer_slice(stack, layer))
+
+    def _combine_step(self, x, ye, alpha, gates):
+        """Steps 4-5: the Eq.-8 weights of the selected experts and the
+        weighted sum of their outputs, added to x."""
+        w = alpha * gates
+        w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)   # Eq. 8
+        y = jnp.einsum("bsed,bse->bsd", ye.astype(jnp.float32),
+                       w).astype(x.dtype)
+        return x + y
+
+    def _unembed_step(self, norm, table, x):
+        return L.unembed(L.rmsnorm(x, norm, self.cfg.norm_eps), table)
 
     def _expert_ffn(self, h, p):
         """Every expert's FFN output for every token: (K, N, E, d).
@@ -173,12 +249,15 @@ class DMoESimulator:
 
         Each pass is one `dmoe.pass` profiler span, with `dmoe.*` spans
         nested at every layer boundary (docs/serving.md, "Tracing a
-        served pass"); they record only while a profiler session runs."""
+        served pass"); they record only while a profiler session runs.
+        The device spans time the dispatch of the jitted steps, and
+        `dmoe.pass` carries `compiles`: the steps' traces in the pass."""
         cfg = self.cfg
         k, n = tokens.shape
         assert k == self.k, "one query per expert node (§III-C step 1)"
         self.passes += 1
-        with TraceAnnotation("dmoe.pass", **{"pass": self.passes}):
+        compiles = self.compiles
+        with TraceAnnotation("dmoe.pass", **{"pass": self.passes}) as span:
             gains = (self.channel_process.step(self.rng)
                      if self.channel_process is not None else
                      channel_lib.sample_channel_gains(self.channel_cfg,
@@ -186,28 +265,26 @@ class DMoESimulator:
             rates = channel_lib.subcarrier_rates(self.channel_cfg, gains)
 
             with TraceAnnotation("dmoe.embed"):
-                x = jnp.take(self.params["embed"], jnp.asarray(tokens),
-                             axis=0)
-                x = x.astype(jnp.float32 if cfg.dtype == "float32"
-                             else jnp.bfloat16)
+                x = self._embed(self.params["embed"], tokens)
 
+            stack = self.params["stages"]["stage0"]
             rounds: List[proto.RoundAccounting] = []
             schedules: List[RoundSchedule] = []
             hist = np.zeros((cfg.num_layers, self.k))
             for layer in range(cfg.num_layers):
                 with TraceAnnotation("dmoe.round", layer=layer + 1,
                                      **{"pass": self.passes}):
-                    x, rs, acct = self._round(x, rates, layer)
+                    x, rs, acct = self._round(x, stack, rates, layer)
                     schedules.append(rs)
                     rounds.append(acct)
                     hist[layer] = rs.alpha.sum(axis=(0, 1)) / max(
                         rs.alpha.sum(), 1)
 
             with TraceAnnotation("dmoe.unembed"):
-                x = L.rmsnorm(x, self.params["final_norm"], cfg.norm_eps)
                 table = (self.params["embed"] if cfg.tie_embeddings
                          else self.params["unembed"])
-                logits = L.unembed(x, table)
+                logits = self._unembed(self.params["final_norm"], table, x)
+            span.set_metadata(compiles=self.compiles - compiles)
             summary = proto.summarize(rounds)
             with TraceAnnotation("dmoe.logits_d2h"):
                 return SimResult(
@@ -218,22 +295,15 @@ class DMoESimulator:
                     schedules=schedules,
                 )
 
-    def _round(self, x, rates: np.ndarray, layer: int):
+    def _round(self, x, stack, rates: np.ndarray, layer: int):
         """One protocol round (steps 2-5) on the hidden states x:
         returns (x after the Eq.-8 combine, the round's schedule, its
         energy accounting)."""
-        cfg = self.cfg
         with TraceAnnotation("dmoe.params"):
-            p = self._layer_params(layer)
+            i = np.int32(layer)
         # -- step 2: attention + gate (in-situ) ------------------------
         with TraceAnnotation("dmoe.attn_gate"):
-            h = L.rmsnorm(x, p["norm1"], cfg.norm_eps)
-            a, _ = A.gqa_prefill(p["attn"], h, cfg, causal=True)
-            x = x + a
-            h = L.rmsnorm(x, p["norm2"], cfg.norm_eps)
-            logits = jnp.einsum("bsd,de->bse", h.astype(jnp.float32),
-                                p["ffn"]["w_gate_router"])
-            gates_dev = jax.nn.softmax(logits, axis=-1)   # (K, N, E)
+            x, h, gates_dev = self._attn_gate(stack, i, x)   # gates (K, N, E)
 
         # -- step 3: joint expert & subcarrier allocation --------------
         # The per-expert FFN outputs don't depend on alpha (selection
@@ -242,24 +312,22 @@ class DMoESimulator:
         # device einsums run concurrently with the host B&B.
         if self.overlap:
             with TraceAnnotation("dmoe.expert_ffn"):
-                ye = self._expert_ffn(h, p)
+                ye = self._ffn(stack, i, h)
         with TraceAnnotation("dmoe.gate_d2h"):
             gates = np.asarray(gates_dev, dtype=np.float64)
         with TraceAnnotation("dmoe.schedule"):
             rs = self._schedule(gates, rates, layer)
         if not self.overlap:
             with TraceAnnotation("dmoe.expert_ffn"):
-                ye = self._expert_ffn(h, p)
+                ye = self._ffn(stack, i, h)
         alpha, beta = rs.alpha, rs.beta
 
         # -- steps 4-5: forward tx + FFN + backward tx + aggregate -----
+        # The gates the scheduler saw, as the device holds them: float32
+        # -> float64 -> float32 is exact, so no copy goes back up.
         with TraceAnnotation("dmoe.combine"):
-            am = jnp.asarray(alpha, dtype=jnp.float32)    # (K, N, E)
-            w = am * jnp.asarray(gates, dtype=jnp.float32)
-            w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)  # Eq. 8
-            y = jnp.einsum("bsed,bse->bsd", ye.astype(jnp.float32),
-                           w).astype(x.dtype)
-            x = x + y
+            x = self._combine(x, ye, np.asarray(alpha, dtype=np.float32),
+                              gates_dev)
 
         with TraceAnnotation("dmoe.account"):
             acct = proto.account_round(

@@ -282,14 +282,9 @@ class ServingFrontend:
         check_routing_impl(cfg.routing_impl)
         if self.mode == "sim" and cfg.routing_impl != "xla":
             # the simulator owns the model: thread the dispatch backend
-            # through to its expert-FFN compute ("grouped" is rejected
-            # there — the protocol's dense all-expert FFN has no ragged
-            # token→expert assignment; mirror that here since we assign
-            # past the constructor)
-            if cfg.routing_impl != "fused":
-                raise ValueError(
-                    "sim mode supports routing_impl 'xla' or 'fused' "
-                    f"(dense all-expert FFN), got {cfg.routing_impl!r}")
+            # through to its expert-FFN compute (its setter rejects
+            # "grouped" — the protocol's dense all-expert FFN has no
+            # ragged token→expert assignment)
             sim.routing_impl = cfg.routing_impl
         elif self.mode == "pool" and cfg.routing_impl != "xla":
             raise ValueError(

@@ -1,0 +1,196 @@
+"""The protocol round's compiled steps (`DMoESimulator`): each jitted
+step traces once per shape, the served logits are the model's own math
+layer by layer, and `overlap` reorders dispatch without changing a bit."""
+
+import warnings
+
+import jax
+import numpy as np
+import pytest
+from jax.profiler import ProfileData
+
+from repro.analysis.sanitizers import recompile_guard
+from repro.configs.base import get_smoke_config
+from repro.schedulers import SchedulerPolicy, get_policy
+from repro.serving import DMoESimulator
+from repro.serving.frontend import FrontendConfig, ServingFrontend
+
+STEPS = ("_embed_step", "_attn_gate_step", "_ffn_step", "_combine_step",
+         "_unembed_step")
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    c = get_smoke_config("mixtral-8x7b")
+    return c.with_overrides(num_layers=2, moe_num_experts=4)
+
+
+class Recording(SchedulerPolicy):
+    """The registry policy, with every round's context kept."""
+
+    def __init__(self, inner):
+        self.inner, self.name, self.contexts = inner, inner.name, []
+
+    def schedule(self, ctx):
+        self.contexts.append(ctx)
+        return self.inner.schedule(ctx)
+
+
+def _pass_compiles(log_dir):
+    """The `compiles` metadata of each `dmoe.pass` span, in time order."""
+    path = sorted(log_dir.glob("plugins/profile/*/*.xplane.pb"))[-1]
+    passes = []
+    with warnings.catch_warnings():
+        # jaxlib's stats type warns of its own missing __module__.
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(str(path)).planes:
+            for line in plane.lines:
+                passes += [(ev.start_ns, dict(ev.stats)["compiles"])
+                           for ev in line.events if ev.name == "dmoe.pass"]
+    return [c for _, c in sorted(passes)]
+
+
+def test_steps_trace_once_per_shape(cfg, tmp_path):
+    rng = np.random.default_rng(0)
+    wave = rng.integers(0, cfg.vocab_size, size=(4, 6))
+    longer = rng.integers(0, cfg.vocab_size, size=(4, 7))
+    sim = DMoESimulator(cfg, scheme="jesa", seed=1)
+    assert sim.compiles == 0
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with recompile_guard({s: 1 for s in STEPS}):
+            sim.serve(wave)
+        first = sim.compiles
+        # The same (K, N): every step hits jit's cache, nothing compiles.
+        with recompile_guard() as log:
+            sim.serve(wave)
+        assert log.counts == {}
+        assert sim.compiles == first
+        # A new N is a new shape: every step traces again, once.
+        with recompile_guard({s: 1 for s in STEPS}):
+            sim.serve(longer)
+    finally:
+        jax.profiler.stop_trace()
+
+    assert first == len(STEPS)
+    assert sim.compiles == 2 * len(STEPS)
+    assert _pass_compiles(tmp_path) == [len(STEPS), 0, len(STEPS)]
+
+
+def _rmsnorm(x, w, eps):
+    return x / np.sqrt(np.mean(x * x, axis=-1, keepdims=True) + eps) * w
+
+
+def _rope(x, theta):
+    """x: (B, S, H, Dh), positions 0..S-1, halves rotated."""
+    s, dh = x.shape[1], x.shape[-1]
+    freqs = 1.0 / theta ** (np.arange(0, dh, 2) / dh)
+    ang = np.arange(s)[:, None] * freqs                   # (S, Dh/2)
+    cos, sin = np.cos(ang)[:, None, :], np.sin(ang)[:, None, :]
+    x1, x2 = x[..., : dh // 2], x[..., dh // 2:]
+    return np.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+
+
+def _attention(p, x, cfg):
+    """Causal GQA: query head j reads kv head j // (H / Hkv)."""
+    s = x.shape[1]
+    q = _rope(np.einsum("bsd,dhe->bshe", x, p["wq"]), cfg.rope_theta)
+    k = _rope(np.einsum("bsd,dhe->bshe", x, p["wk"]), cfg.rope_theta)
+    v = np.einsum("bsd,dhe->bshe", x, p["wv"])
+    rep = cfg.num_heads // cfg.num_kv_heads
+    k, v = np.repeat(k, rep, axis=2), np.repeat(v, rep, axis=2)
+    scores = np.einsum("bqhe,bkhe->bhqk", q, k) / np.sqrt(q.shape[-1])
+    scores = np.where(np.tril(np.ones((s, s), bool)), scores, -np.inf)
+    probs = np.exp(scores - scores.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    out = np.einsum("bhqk,bkhe->bqhe", probs, v)
+    return np.einsum("bshe,hed->bsd", out, p["wo"])
+
+
+def _reference(params, tokens, alphas, cfg):
+    """The pass in float64 NumPy from the simulator's float32 weights,
+    layer by layer and expert by expert, each round's combine
+    teacher-forced on the selection the scheduler made: (logits, gates
+    of every layer)."""
+    p = jax.tree.map(lambda a: np.asarray(a, np.float64), params)
+    stack, eps = p["stages"]["stage0"], cfg.norm_eps
+    x = p["embed"][tokens]
+    gates = []
+    for layer, alpha in enumerate(alphas):
+        lp = jax.tree.map(lambda a: a[layer], stack)
+        x = x + _attention(lp["attn"], _rmsnorm(x, lp["norm1"], eps), cfg)
+        h = _rmsnorm(x, lp["norm2"], eps)
+        logits = h @ lp["ffn"]["w_gate_router"]
+        g = np.exp(logits - logits.max(-1, keepdims=True))
+        g /= g.sum(-1, keepdims=True)
+        gates.append(g)
+        w = alpha * g
+        w /= np.maximum(w.sum(-1, keepdims=True), 1e-9)      # Eq. 8
+        for e in range(w.shape[-1]):
+            f = lp["ffn"]
+            a = h @ f["w1"][e]
+            y = (a / (1.0 + np.exp(-a))) * (h @ f["wu"][e]) @ f["w2"][e]
+            x = x + w[..., e:e + 1] * y
+    x = _rmsnorm(x, p["final_norm"], eps)
+    return x @ p["unembed"].T, gates
+
+
+def test_served_logits_match_layerwise_reference(cfg):
+    assert cfg.dtype == "float32" and not cfg.tie_embeddings
+    tokens = np.random.default_rng(2).integers(0, cfg.vocab_size, (4, 6))
+    policy = Recording(get_policy("jesa"))
+    sim = DMoESimulator(cfg, policy=policy, seed=4)
+    res = sim.serve(tokens)
+    want, want_gates = _reference(sim.params, tokens,
+                                  [rs.alpha for rs in res.schedules], cfg)
+    np.testing.assert_allclose(res.logits, want, rtol=1e-4, atol=1e-5)
+    for ctx, g in zip(policy.contexts, want_gates, strict=True):
+        np.testing.assert_allclose(ctx.gate_scores, g, rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("impl", ["xla", "fused"])
+def test_overlap_reorders_dispatch_only(cfg, impl):
+    tokens = np.random.default_rng(5).integers(0, cfg.vocab_size, (4, 6))
+    got = {}
+    for overlap in (True, False):
+        sim = DMoESimulator(cfg, scheme="jesa", seed=6, overlap=overlap,
+                            routing_impl=impl)
+        got[overlap] = sim.serve(tokens)
+    on, off = got[True], got[False]
+    assert np.array_equal(on.logits, off.logits)
+    assert on.summary == off.summary
+    for a, b in zip(on.schedules, off.schedules, strict=True):
+        assert np.array_equal(a.alpha, b.alpha)
+        assert np.array_equal(a.beta, b.beta)
+        assert a.energy == b.energy
+
+
+def test_routing_impl_set_after_construction_retraces(cfg):
+    """`ServingFrontend` sets the backend on a built simulator: the FFN
+    step then traces the new backend instead of reusing the old one."""
+    tokens = np.random.default_rng(7).integers(0, cfg.vocab_size, (4, 6))
+    fused = DMoESimulator(cfg, scheme="jesa", seed=8, routing_impl="fused")
+    fused.serve(tokens)
+    want = fused.serve(tokens).logits
+    sim = DMoESimulator(cfg, scheme="jesa", seed=8)
+    sim.serve(tokens)
+    sim.routing_impl = "fused"
+    before = sim.compiles
+    assert np.array_equal(sim.serve(tokens).logits, want)
+    assert sim.compiles == before + 1          # the FFN step alone
+    with pytest.raises(ValueError, match="routing_impl"):
+        sim.routing_impl = "grouped"
+
+
+@pytest.mark.parametrize("impl", ["fused", "grouped"])
+def test_frontend_threads_routing_impl_into_sim(cfg, impl):
+    sim = DMoESimulator(cfg, scheme="jesa", seed=9)
+    config = FrontendConfig(routing_impl=impl)
+    if impl == "grouped":
+        with pytest.raises(ValueError, match="routing_impl"):
+            ServingFrontend(sim=sim, cfg=config)
+        assert sim.routing_impl == "xla"
+    else:
+        ServingFrontend(sim=sim, cfg=config)
+        assert sim.routing_impl == "fused"
