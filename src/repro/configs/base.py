@@ -53,6 +53,7 @@ class SSMConfig:
     head_dim: int = 64                # rwkv6 head size
     attn_every: int = 0               # hybrid: attention layer period (jamba: 8)
     scan_chunk: int = 1024            # mamba: SSM recurrence chunk length
+    inner_norms: bool = False         # mamba: RMSNorm on dt, B and C (Jamba)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,6 +71,7 @@ class ModelConfig:
     vocab_size: int = 32000
 
     rope_theta: float = 1e6
+    rope: bool = True                 # rotary positions in GQA (Jamba: none)
     max_seq_len: int = 131072
     sliding_window: int = 0           # 0 = full attention
     norm_eps: float = 1e-5
@@ -195,6 +197,7 @@ ARCH_ALIASES = {
     "chameleon-34b": "chameleon_34b",
     "rwkv6-7b": "rwkv6_7b",
     "jamba-1.5-large-398b": "jamba_15_large",
+    "jamba2-mini": "jamba2_mini",
     "stablelm-1.6b": "stablelm_16b",
     "deepseek-v3-671b": "deepseek_v3",
     # paper's own model

@@ -1,6 +1,7 @@
 """jamba-1.5-large-398b [hybrid] — 72L d_model=8192 64H (GQA kv=8)
 d_ff=24576, MoE 16e top-2 — Mamba+attention 1:7 interleave (period 8),
-MoE every 2nd layer.  [arXiv:2403.19887]"""
+MoE every 2nd layer; no positional encoding, RMSNorms on Mamba's dt, B
+and C.  [arXiv:2403.19887]"""
 
 import dataclasses
 
@@ -16,8 +17,9 @@ CONFIG = ModelConfig(
     num_kv_heads=8,
     d_ff=24576,
     vocab_size=65536,
-    rope_theta=1e6,
+    rope=False,
     max_seq_len=262144,
+    norm_eps=1e-6,
     sliding_window=4096,     # used by its attention layers at long ctx
     moe=MoEConfig(
         num_experts=16,
@@ -28,7 +30,8 @@ CONFIG = ModelConfig(
         qos_gamma0=0.7,
         max_experts=2,
     ),
-    ssm=SSMConfig(kind="mamba", d_state=16, d_conv=4, expand=2, attn_every=8),
+    ssm=SSMConfig(kind="mamba", d_state=16, d_conv=4, expand=2, attn_every=8,
+                  inner_norms=True),
 )
 
 

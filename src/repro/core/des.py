@@ -25,6 +25,17 @@ exclusion of the critical expert removes (t - z)/t_j of it, i.e.
 ``e <- e - (t - z) * e_j / t_j``.  We implement the corrected form (it is
 the unique value consistent with Eq. (11)).
 
+Unreachable experts (cost +inf: a link with no subcarrier) are exact.  In
+an instance with some finite costs each unreachable expert is priced one
+joule above the sum of the finite costs (`_price_unreachable`): dearer
+than any reachable selection, yet small enough that the B&B's sums keep
+the 1e-3 J costs to the last bits, where `_BIG` = 1e15 would round them
+away.  So an unreachable expert is chosen only where no reachable subset
+meets the QoS; there every subset that does costs +inf, and Remark 2's
+Top-D by score is taken, priced +inf.  Instances that are wholly
+unreachable, infeasible anyway, or force an unreachable expert in keep
+the `_BIG` clamp below.
+
 The problem is NP-hard (Prop. 1, knapsack reduction) so worst-case cost is
 exponential, but the bound prunes aggressively (see
 benchmarks/des_complexity.py).  A brute-force oracle is provided for tests.
@@ -39,7 +50,8 @@ from typing import Optional
 
 import numpy as np
 
-# Stand-in for +inf costs (unreachable experts); keeps LP math finite.
+# Stand-in for +inf costs where an instance is not priced by
+# `_price_unreachable`; keeps LP math finite.
 # Small enough that even K * _BIG sums and the fractional-exclusion terms
 # of Eq. (11)-(12) stay far from float64 overflow (and survive a float32
 # downcast in consumers), large enough to dominate any physical energy.
@@ -67,6 +79,30 @@ def _sanitize_batch(e_raw: np.ndarray) -> np.ndarray:
     (`repro.schedulers.sharded`); the jax replica is
     `repro.core.des_prework.sanitize_costs`."""
     return np.minimum(np.where(np.isfinite(e_raw), e_raw, _BIG), _BIG)
+
+
+def _partly_reachable(e_raw: np.ndarray, forced: np.ndarray) -> np.ndarray:
+    """Rows (B,) with some finite and some non-finite costs, and no forced
+    expert among the non-finite ones: the rows `_price_unreachable`
+    prices.  The jax replica is `repro.core.des_prework.partly_reachable`
+    (the device pre-work leaves these rows to the host)."""
+    fin = np.isfinite(e_raw)
+    return fin.any(axis=1) & ~fin.all(axis=1) & ~(forced & ~fin).any(axis=1)
+
+
+def _price_unreachable(e_raw: np.ndarray) -> np.ndarray:
+    """(B, K) costs with each non-finite one replaced by 1 + the row's sum
+    of finite costs: above any selection of reachable experts, and of
+    the order of the costs, so the B&B's sums stay exact."""
+    fin = np.isfinite(e_raw)
+    big = 1.0 + np.where(fin, e_raw, 0.0).sum(axis=1, keepdims=True)
+    return np.where(fin, e_raw, big)
+
+
+def _unreachable_chosen(selected: np.ndarray, e_raw: np.ndarray) -> np.ndarray:
+    """Rows (B,) whose selection holds an unreachable expert: no reachable
+    subset met the QoS."""
+    return (selected & ~np.isfinite(e_raw)).any(axis=1)
 
 
 def _batch_inputs(scores, costs, qos, force_include):
@@ -189,6 +225,11 @@ def des_select(
         energy = float("inf") if all_unreachable else float(e[sel].sum())
         return DESResult(sel, energy, False, 0, 0)
 
+    e_raw = np.asarray(costs, dtype=np.float64)
+    partial = bool(_partly_reachable(e_raw[None], forced[None])[0])
+    if partial:
+        e = _price_unreachable(e_raw[None])[0]
+
     # Sort by energy-to-score ratio descending (paper's branch order).
     with np.errstate(divide="ignore"):
         ratio = np.where(t > 0, e / np.maximum(t, 1e-300), np.inf)
@@ -276,6 +317,9 @@ def des_select(
     # Map back to original order.
     selected = np.zeros(k, dtype=bool)
     selected[order[sel_min]] = True
+    if partial and _unreachable_chosen(selected[None], e_raw[None])[0]:
+        return DESResult(top_d_fallback(t, e, d), float("inf"), False,
+                         explored, pruned)
     return DESResult(selected, float(e[selected].sum()), True, explored, pruned)
 
 
@@ -447,6 +491,8 @@ def des_select_batch(
     live = np.flatnonzero(~infeasible)
     if live.size == 0:
         return DESBatchResult(selected, energy, feasible, explored, pruned)
+    partial = live[_partly_reachable(e_raw[live], forced[live])]
+    e[partial] = _price_unreachable(e_raw[partial])
 
     # ---- ratio sort (paper's branch order), batched ----------------------
     tl, el, zl, fl = t[live], e[live], z[live], forced[live]
@@ -493,6 +539,18 @@ def des_select_batch(
             feasible[rows] = sub.feasible
             explored[rows] = sub.nodes_explored
             pruned[rows] = sub.nodes_pruned
+            partial = np.setdiff1d(partial, rows)
+    # Partly reachable rows that had to take an unreachable expert: no
+    # reachable subset meets the QoS.  Remark 2, priced +inf.
+    bad = partial[feasible[partial]
+                  & _unreachable_chosen(selected[partial], e_raw[partial])]
+    if bad.size:
+        top = np.argsort(-t[bad], axis=1, kind="stable")[:, : min(d, k)]
+        sel = np.zeros((bad.size, k), dtype=bool)
+        np.put_along_axis(sel, top, True, axis=1)
+        selected[bad] = sel
+        energy[bad] = np.inf
+        feasible[bad] = False
     return DESBatchResult(selected, energy, feasible, explored, pruned)
 
 
@@ -585,12 +643,14 @@ class WarmStartCache:
         self.stats["invalidations"] += 1
 
     def _hash(self, key: np.ndarray) -> np.ndarray:
-        # same deliberate-constant hash definition as `_dedup_rows`
+        # `_dedup_rows`' weights, summed row by row: a matrix product's
+        # rounding may depend on how many rows it is given, and a row
+        # stored from one batch must hash alike when looked up in another
         w = key.shape[1]
         if w not in self._weights:
             weights = np.random.default_rng(0xDE5).standard_normal(w)
             self._weights[w] = weights
-        return key @ self._weights[w]
+        return (key * self._weights[w]).sum(axis=1)
 
     def match(self, full_key: np.ndarray):
         """Exact-tier lookup: (hit (B,) bool, sel (B, K'), energy (B,),
@@ -936,24 +996,30 @@ def _branch_and_bound_batch(ts, es, qos, d, forced_s, upper_bound=None):
 def des_select_brute_force(
     scores: np.ndarray, costs: np.ndarray, qos: float, max_experts: int
 ) -> DESResult:
-    """O(2^K) oracle for tests (K <= ~16)."""
+    """O(2^K) oracle for tests (K <= ~16): the cheapest subset of at most
+    D experts that meets the QoS, over the finite-energy subsets; where a
+    subset meets it but none of finite energy does, Remark 2's Top-D
+    priced +inf (the solvers' rule for unreachable experts)."""
     t = np.asarray(scores, dtype=np.float64)
+    e_raw = np.asarray(costs, dtype=np.float64)
     e = _sanitize(costs)
     k = t.shape[0]
-    if not np.isfinite(np.asarray(costs, dtype=np.float64)).any():
+    if not np.isfinite(e_raw).any():
         sel = top_d_fallback(t, e, max_experts)
         return DESResult(sel, float("inf"), False, 0, 0)
-    best_e, best_sel = np.inf, None
+    best_e, best_sel, meets = np.inf, None, False
     for bits in range(1 << k):
         sel = np.array([(bits >> b) & 1 for b in range(k)], dtype=bool)
         if sel.sum() > max_experts:
             continue
         if t[sel].sum() < qos:
             continue
-        ee = e[sel].sum()
+        meets = True
+        ee = e_raw[sel].sum()
         if ee < best_e:
             best_e, best_sel = ee, sel
     if best_sel is None:
         sel = top_d_fallback(t, e, max_experts)
-        return DESResult(sel, float(e[sel].sum()), False, 1 << k, 0)
+        energy = float("inf") if meets else float(e[sel].sum())
+        return DESResult(sel, energy, False, 1 << k, 0)
     return DESResult(best_sel, float(best_e), True, 1 << k, 0)

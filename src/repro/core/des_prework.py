@@ -109,6 +109,14 @@ def sanitize_costs(e_raw: jnp.ndarray) -> jnp.ndarray:
     return jnp.minimum(jnp.where(jnp.isfinite(e_raw), e_raw, _BIG), _BIG)
 
 
+def partly_reachable(e_raw: jnp.ndarray, forced: jnp.ndarray) -> jnp.ndarray:
+    """`des._partly_reachable`: rows with finite and non-finite costs and
+    no forced non-finite one.  The host prices their unreachable experts
+    (`des._price_unreachable`), so they are never resolved in-graph."""
+    fin = jnp.isfinite(e_raw)
+    return fin.any(axis=1) & ~fin.all(axis=1) & ~(forced & ~fin).any(axis=1)
+
+
 def _top_d_score(t: jnp.ndarray, d: int) -> jnp.ndarray:
     """Remark-2 screen statistic: sum of the D highest scores per row,
     accumulated exactly as `np.sort(t, axis=1)[:, ::-1][:, :d].sum(axis=1)`."""
@@ -159,11 +167,13 @@ def prework(scores: jnp.ndarray, costs: jnp.ndarray, qos: jnp.ndarray,
     Returns a dict of per-row arrays (all in ORIGINAL expert order):
       infeasible      (B,)  bool — Remark-2 screen failed;
       all_unreachable (B,)  bool — every raw cost was non-finite;
+      partial         (B,)  bool — `partly_reachable`: left to the host;
       fallback_sel    (B, K) bool — Top-D-by-score fallback selection
                       (valid for infeasible rows without forced experts);
-      easy            (B,)  bool — feasible, greedy seed integral within
-                      budget, and the root LP bound proves it optimal
-                      (the B&B would prune its root node immediately);
+      easy            (B,)  bool — feasible, not `partial`, greedy seed
+                      integral within budget, and the root LP bound
+                      proves it optimal (the B&B would prune its root
+                      node immediately);
       easy_sel        (B, K) bool — the seed selection for easy rows;
       seed_energy     (B,)  float64 — incumbent energy (diagnostics);
       root_bound      (B,)  float64 — root LP bound (diagnostics).
@@ -176,6 +186,7 @@ def prework(scores: jnp.ndarray, costs: jnp.ndarray, qos: jnp.ndarray,
 
     e = sanitize_costs(e_raw)
     all_unreachable = ~jnp.isfinite(e_raw).any(axis=1)
+    partial = partly_reachable(e_raw, forced)
 
     # ---- Remark-2 feasibility screen + Top-D fallback ------------------
     top_d_score = _top_d_score(t, d)
@@ -217,7 +228,7 @@ def prework(scores: jnp.ndarray, costs: jnp.ndarray, qos: jnp.ndarray,
     # The sequential solver prunes its root iff bound >= e_min - 1e-12
     # with e_min the seed energy; identical expression, identical floats.
     root_prunes = root_bound >= seed_energy - 1e-12
-    easy = (~infeasible & seeded & (seed_count < _SMALL_SUM)
+    easy = (~infeasible & ~partial & seeded & (seed_count < _SMALL_SUM)
             & (tt0 >= z) & root_prunes)
 
     # scatter the seed back to original expert order via the inverse perm
@@ -227,6 +238,7 @@ def prework(scores: jnp.ndarray, costs: jnp.ndarray, qos: jnp.ndarray,
     return {
         "infeasible": infeasible,
         "all_unreachable": all_unreachable,
+        "partial": partial,
         "fallback_sel": fallback_sel,
         "easy": easy,
         "easy_sel": easy_sel,

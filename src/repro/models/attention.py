@@ -180,8 +180,9 @@ def gqa_prefill(params, x, cfg: ModelConfig, *, causal: bool = True,
     q = jnp.einsum("bsd,dhe->bshe", x, params["wq"])
     k = jnp.einsum("bsd,dhe->bshe", x, params["wk"])
     v = jnp.einsum("bsd,dhe->bshe", x, params["wv"])
-    q = L.apply_rope(q, positions[None, :], cfg.rope_theta)
-    k = L.apply_rope(k, positions[None, :], cfg.rope_theta)
+    if cfg.rope:
+        q = L.apply_rope(q, positions[None, :], cfg.rope_theta)
+        k = L.apply_rope(k, positions[None, :], cfg.rope_theta)
     qg = q.reshape(b, s, hkv, r, dh)
 
     if s > cfg.attn_chunk_threshold:
@@ -221,8 +222,9 @@ def gqa_decode(params, x, cache, cfg: ModelConfig, *, window: int = 0,
     q = jnp.einsum("bsd,dhe->bshe", x, params["wq"])
     k_new = jnp.einsum("bsd,dhe->bshe", x, params["wk"])
     v_new = jnp.einsum("bsd,dhe->bshe", x, params["wv"])
-    q = L.apply_rope(q, pos, cfg.rope_theta)
-    k_new = L.apply_rope(k_new, pos, cfg.rope_theta)
+    if cfg.rope:
+        q = L.apply_rope(q, pos, cfg.rope_theta)
+        k_new = L.apply_rope(k_new, pos, cfg.rope_theta)
 
     rows = jnp.arange(b)
     k_cache = cache["k"].at[rows, idx].set(

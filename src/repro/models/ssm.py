@@ -190,7 +190,7 @@ def init_mamba(key, cfg: ModelConfig, dtype) -> Dict[str, jnp.ndarray]:
     ks = jax.random.split(key, 6)
     dt_rank = max(d // 16, 1)
     a_init = jnp.tile(jnp.arange(1, n + 1, dtype=jnp.float32)[None], (di, 1))
-    return {
+    params = {
         "w_in": L.dense_init(ks[0], d, 2 * di, dtype),
         "conv_w": (jax.random.normal(ks[1], (kconv, di), dtype=jnp.float32)
                    / np.sqrt(kconv)).astype(dtype),
@@ -202,14 +202,26 @@ def init_mamba(key, cfg: ModelConfig, dtype) -> Dict[str, jnp.ndarray]:
         "d_skip": jnp.ones((di,), dtype=jnp.float32),
         "w_out": L.dense_init(ks[4], di, d, dtype),
     }
+    if cfg.ssm.inner_norms:
+        params.update(dt_norm=L.rmsnorm_init(dt_rank, dtype),
+                      b_norm=L.rmsnorm_init(n, dtype),
+                      c_norm=L.rmsnorm_init(n, dtype))
+    return params
 
 
 def _mamba_bcdt(params, xc, cfg):
+    """B, C and dt from the convolved input.  With `inner_norms` (Jamba)
+    each of dt's low-rank input, B and C passes an RMSNorm first."""
     n = cfg.ssm.d_state
     bcdt = jnp.einsum("bsd,de->bse", xc, params["w_bcdt"])
     b_mat = bcdt[..., :n]
     c_mat = bcdt[..., n:2 * n]
-    dt = jnp.einsum("bsr,rd->bsd", bcdt[..., 2 * n:], params["w_dt"])
+    dt_in = bcdt[..., 2 * n:]
+    if cfg.ssm.inner_norms:
+        b_mat = L.rmsnorm(b_mat, params["b_norm"], cfg.norm_eps)
+        c_mat = L.rmsnorm(c_mat, params["c_norm"], cfg.norm_eps)
+        dt_in = L.rmsnorm(dt_in, params["dt_norm"], cfg.norm_eps)
+    dt = jnp.einsum("bsr,rd->bsd", dt_in, params["w_dt"])
     dt = jax.nn.softplus(dt.astype(jnp.float32)
                          + params["dt_bias"].astype(jnp.float32))
     return b_mat, c_mat, dt

@@ -42,6 +42,17 @@ def jamba_attn_pos(cfg) -> int:
     return jamba_period(cfg) // 2
 
 
+def jamba_sublayers(cfg) -> List[Tuple[str, bool]]:
+    """(mixer kind, MoE FFN?) of each sublayer of a Jamba period:
+    attention at `jamba_attn_pos`, Mamba elsewhere; MoE where the index
+    is 1 modulo `moe.every` (odd sublayers at every=2), a dense SwiGLU
+    elsewhere.  The engine's stack and the protocol simulator both walk
+    this plan."""
+    return [("attention" if i == jamba_attn_pos(cfg) else "mamba",
+             bool(cfg.moe.num_experts) and i % cfg.moe.every == 1)
+            for i in range(jamba_period(cfg))]
+
+
 # ----------------------------------------------------------------------
 # per-kind init
 # ----------------------------------------------------------------------
@@ -84,11 +95,10 @@ def init_block(kind: str, key, cfg: ModelConfig, dtype):
         subs = {}
         period = jamba_period(cfg)
         keys = jax.random.split(key, period * 2)
-        for i in range(period):
+        for i, (kind_i, use_moe) in enumerate(jamba_sublayers(cfg)):
             km, kf = keys[2 * i], keys[2 * i + 1]
-            mixer = (A.init_gqa(km, cfg, dtype) if i == jamba_attn_pos(cfg)
+            mixer = (A.init_gqa(km, cfg, dtype) if kind_i == "attention"
                      else S.init_mamba(km, cfg, dtype))
-            use_moe = (i % cfg.moe.every) == 1 if cfg.moe.num_experts else False
             ffn = (M.init_moe(kf, cfg, dtype) if use_moe
                    else L.swiglu_init(kf, cfg.d_model, cfg.d_ff, dtype))
             subs[f"sub{i}"] = {
@@ -127,8 +137,8 @@ def init_block_cache(kind: str, batch: int, max_len: int, cfg: ModelConfig,
         return S.init_rwkv6_state(batch, cfg, dtype)
     if kind == "jamba":
         cache = {}
-        for i in range(jamba_period(cfg)):
-            if i == jamba_attn_pos(cfg):
+        for i, (kind_i, _) in enumerate(jamba_sublayers(cfg)):
+            if kind_i == "attention":
                 cache[f"sub{i}"] = A.init_kv_cache(
                     batch, max_len, cfg.num_kv_heads, dh, dtype)
             else:
@@ -231,12 +241,12 @@ def block_forward(
         aux_acc = _zero_aux()
         n_moe = 0
         period = jamba_period(cfg)
-        for i in range(period):
+        for i, (kind_i, use_moe) in enumerate(jamba_sublayers(cfg)):
             sub = params[f"sub{i}"]
             sub_cache = None if cache is None else cache[f"sub{i}"]
             li = layer_idx * period + i
             h = L.rmsnorm(x, sub["norm1"], eps)
-            if i == jamba_attn_pos(cfg):
+            if kind_i == "attention":
                 if mode == "full":
                     a, sub_cache = A.gqa_prefill(sub["mixer"], h, cfg,
                                                  causal=True, window=window,
@@ -255,7 +265,6 @@ def block_forward(
                                                   cfg)
             x = x + a
             h = L.rmsnorm(x, sub["norm2"], eps)
-            use_moe = (i % cfg.moe.every) == 1 if cfg.moe.num_experts else False
             y, aux = _ffn_apply(sub["ffn"], h, cfg, li, use_moe, expert_costs)
             if use_moe:
                 n_moe += 1

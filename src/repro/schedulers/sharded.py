@@ -84,7 +84,8 @@ def _sharded_prework_fn(mesh, max_experts: int):
     row = P(BATCH_AXIS)
     mat = P(BATCH_AXIS, None)
     out_specs = {
-        "infeasible": row, "all_unreachable": row, "fallback_sel": mat,
+        "infeasible": row, "all_unreachable": row, "partial": row,
+        "fallback_sel": mat,
         "easy": row, "easy_sel": mat, "seed_energy": row, "root_bound": row,
     }
     # named wrapper (not a bare functools.partial) so the compilation
@@ -280,7 +281,7 @@ def resolve_prework(
             rb = pw["root_bound"][bnb_rows]
             se = pw["seed_energy"][bnb_rows]
             easy_w = (np.isfinite(ub) & (rb >= ub + 1e-12)
-                      & (se <= ub + 1e-12))
+                      & (se <= ub + 1e-12) & ~pw["partial"][bnb_rows])
             if easy_w.any():
                 rows = bnb_rows[easy_w]
                 sel = pw["easy_sel"][rows]

@@ -28,6 +28,28 @@ separate executables on purpose: an executable's outputs are ready
 only when all of it ends, so a fused step would hold the gate scores
 back until the FFN were done.
 
+Hybrid blocks (Jamba, `arch_type="hybrid"`): the simulator walks the
+period's layer plan (`models.transformer.jamba_sublayers`).  Every
+sublayer runs one jitted mixer step -- RMSNorm, Mamba or attention, the
+residual -- and at a MoE sublayer the FFN's norm and the gate with it.
+A MoE sublayer is then one protocol round as above (the QoS l counts
+rounds); a dense sublayer's SwiGLU runs in situ at the query's node in
+its own jitted step, with no scheduler call and no transmission.  Every
+node holds the shared blocks (Eq. 6): mixers and dense FFNs included.
+The steps read the stacked period weights by a traced period index and
+take the sublayer's subtree, so one executable serves every period and
+every sublayer of one shape.
+
+Nodes over chips: with a 1-D device `mesh` (its one axis is the edge
+node), each chip holds K / chips nodes -- their queries' hidden states
+(the K axis) and their experts (the E axis of the expert weights) --
+and a replica of the shared blocks.  XLA's partitioner makes the
+exchange: every token's FFN input reaches every chip that holds experts
+(the protocol's step-4 transmission) and each chip's weighted expert
+outputs return to the tokens' chips (step 5).  The scheduler stays one
+global host policy.  With no mesh, or a mesh of one device, nothing
+is placed or constrained.
+
 Overlap-aware round loop: the expert FFN einsums are dense in the expert
 axis and independent of the selection alpha (alpha only weights the
 Eq.-8 combine), so with ``overlap=True`` (the default) they are
@@ -57,6 +79,8 @@ from repro.core.gating import QoSSchedule
 from repro.models import attention as A
 from repro.models import layers as L
 from repro.models import model as model_lib
+from repro.models import ssm as S
+from repro.models import transformer as T
 from repro.schedulers import RoundSchedule, ScheduleContext, SchedulerPolicy
 from repro.schedulers import get_policy
 
@@ -69,12 +93,36 @@ def _layer_slice(stack, layer):
         stack)
 
 
+def node_mesh(devices=None) -> jax.sharding.Mesh:
+    """A 1-D mesh over `devices` (default: all) whose one axis, "node",
+    is the edge node."""
+    return jax.sharding.Mesh(jax.devices() if devices is None else devices,
+                             ("node",))
+
+
+def node_shardings(params, mesh: jax.sharding.Mesh):
+    """Where each weight lives on a node mesh: the stacked expert weights
+    (`ffn/w1|wu|w2`, (layers, E, ...)) split on E, one chip's share of
+    the nodes; everything else replicated."""
+    axis = mesh.axis_names[0]
+
+    def spec(path, leaf):
+        names = [str(getattr(k, "key", k)) for k in path]
+        expert = (names[-1] in ("w1", "wu", "w2") and "ffn" in names
+                  and leaf.ndim == 4)
+        return jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec(None, axis) if expert
+            else jax.sharding.PartitionSpec())
+
+    return jax.tree_util.tree_map_with_path(spec, params)
+
+
 @dataclasses.dataclass
 class SimResult:
     logits: np.ndarray                 # (K, N, V)
     rounds: List[proto.RoundAccounting]
     summary: Dict
-    selection_hist: np.ndarray         # (L, K) expert selection frequency
+    selection_hist: np.ndarray         # (rounds, K) expert selection frequency
     #: the per-round policy decisions (one `RoundSchedule` per layer) —
     #: recorded so serving front-ends can prove their per-round schedules
     #: bit-identical to an offline simulator run on the same trace.
@@ -85,7 +133,9 @@ class DMoESimulator:
     """Serve queries through the DMoE protocol with a real (small) MoE
     model supplying gates and FFN compute.
 
-    cfg must be an arch_type="moe" config whose num_experts == K nodes.
+    cfg must be an arch_type="moe" config, or a "hybrid" (Jamba) one
+    with Mamba mixers, whose num_experts == K nodes.  `mesh`: an optional
+    1-D device mesh over which the K nodes are spread (module docstring).
     """
 
     def __init__(self, cfg: ModelConfig, *, scheme: str = "jesa",
@@ -96,11 +146,22 @@ class DMoESimulator:
                      channel_lib.ChannelProcess] = None,
                  seed: int = 0, top_k: Optional[int] = None,
                  count_backward: bool = True, overlap: bool = True,
-                 routing_impl: str = "xla"):
-        assert cfg.moe.num_experts >= 1 and cfg.arch_type == "moe"
+                 routing_impl: str = "xla",
+                 mesh: Optional[jax.sharding.Mesh] = None):
+        assert cfg.moe.num_experts >= 1
+        assert cfg.arch_type == "moe" or (
+            cfg.arch_type == "hybrid" and cfg.ssm.kind == "mamba"), \
+            "simulator serves the GQA MoE block or the Jamba hybrid"
         assert not cfg.mla, "simulator uses the plain GQA MoE block"
         self.cfg = cfg
         self.k = cfg.moe.num_experts
+        #: (mixer kind, protocol round?) per sublayer of a hybrid period
+        self.plan = (T.jamba_sublayers(cfg) if cfg.arch_type == "hybrid"
+                     else None)
+        self.mesh = mesh if mesh is not None and mesh.size > 1 else None
+        if self.mesh is not None:
+            assert len(self.mesh.axis_names) == 1, "a 1-D node mesh"
+            assert self.k % self.mesh.size == 0, "whole nodes per chip"
         #: traces of the jitted steps so far: a Python side effect of
         #: each step's body, so it counts once per new shape (or new
         #: backend), never per call
@@ -109,6 +170,9 @@ class DMoESimulator:
         self._attn_gate = self._jit(self._attn_gate_step)
         self._combine = self._jit(self._combine_step)
         self._unembed = self._jit(self._unembed_step)
+        self._mixer = self._jit(self._mixer_step,
+                                static_argnames=("mixer", "gate"))
+        self._dense_ffn = self._jit(self._dense_ffn_step)
         self.routing_impl = routing_impl     # validates; builds the FFN step
         # `scheme` is any registry name; a pre-constructed policy instance
         # (with custom kwargs) may be passed directly instead.
@@ -125,6 +189,9 @@ class DMoESimulator:
         self.channel_process = channel_process
         self.rng = np.random.default_rng(seed)
         self.params = model_lib.init_params(jax.random.PRNGKey(seed), cfg)
+        if self.mesh is not None:
+            self.params = jax.device_put(
+                self.params, node_shardings(self.params, self.mesh))
         self.comp_coeff = energy_lib.make_comp_coeffs(self.k)
         self.s0 = 8192.0
         self.top_k = top_k or cfg.moe.top_k
@@ -161,19 +228,53 @@ class DMoESimulator:
         # A fresh jit: what the old one traced ran the other backend.
         self._ffn = self._jit(self._ffn_step)
 
-    def _jit(self, step):
+    def _jit(self, step, static_argnames=()):
         """`jax.jit` of a step that counts its own traces in `compiles`."""
         @functools.wraps(step)
-        def traced(*args):
+        def traced(*args, **kwargs):
             self.compiles += 1          # runs only while jax traces
-            return step(*args)
-        return jax.jit(traced)
+            return step(*args, **kwargs)
+        return jax.jit(traced, static_argnames=static_argnames)
+
+    @property
+    def chips(self) -> int:
+        return 1 if self.mesh is None else self.mesh.size
+
+    def _placed(self, a, axis: Optional[int]):
+        """`a` split on `axis` over the node mesh (None: replicated); `a`
+        unchanged with no mesh."""
+        if self.mesh is None:
+            return a
+        spec = [None] * a.ndim
+        if axis is not None:
+            spec[axis] = self.mesh.axis_names[0]
+        return jax.lax.with_sharding_constraint(a, jax.sharding.NamedSharding(
+            self.mesh, jax.sharding.PartitionSpec(*spec)))
+
+    def xchip_bytes(self, k: int, n: int) -> int:
+        """Bytes a pass of (k, n) tokens moves between chips, from the
+        shapes and the mesh: each round's FFN input (activation dtype)
+        goes from its token's chip to every other chip, and each chip's
+        float32 sum of its experts' weighted outputs goes back to every
+        other chip's tokens."""
+        if self.mesh is None:
+            return 0
+        act = 4 if self.cfg.dtype == "float32" else 2
+        per_round = (self.chips - 1) * k * n * self.cfg.d_model * (act + 4)
+        return per_round * self.rounds_per_pass
+
+    @property
+    def rounds_per_pass(self) -> int:
+        if self.plan is None:
+            return self.cfg.num_layers
+        return (self.cfg.num_layers // len(self.plan)) * sum(
+            moe for _, moe in self.plan)
 
     # -- the jitted steps ------------------------------------------------
     def _embed_step(self, table, tokens):
         x = jnp.take(table, tokens, axis=0)
-        return x.astype(jnp.float32 if self.cfg.dtype == "float32"
-                        else jnp.bfloat16)
+        return self._placed(x.astype(jnp.float32 if self.cfg.dtype ==
+                                     "float32" else jnp.bfloat16), 0)
 
     def _attn_gate_step(self, stack, layer, x):
         """Step 2 (in-situ): the layer's attention and gate.  Returns x
@@ -183,23 +284,53 @@ class DMoESimulator:
         p = _layer_slice(stack, layer)
         h = L.rmsnorm(x, p["norm1"], cfg.norm_eps)
         a, _ = A.gqa_prefill(p["attn"], h, cfg, causal=True)
-        x = x + a
-        h = L.rmsnorm(x, p["norm2"], cfg.norm_eps)
+        return self._gate(p, x + a)
+
+    def _gate(self, p, x):
+        """The FFN's norm and the router's softmax: (x, h, gates)."""
+        h = L.rmsnorm(x, p["norm2"], self.cfg.norm_eps)
         logits = jnp.einsum("bsd,de->bse", h.astype(jnp.float32),
                             p["ffn"]["w_gate_router"])
         return x, h, jax.nn.softmax(logits, axis=-1)
 
+    def _mixer_step(self, sub, period, x, *, mixer: str, gate: bool):
+        """A hybrid sublayer's mixer (in situ): RMSNorm, Mamba or
+        attention (no rotary positions where the config has none), the
+        residual; with `gate`, also the FFN's norm and the gate scores
+        (K, N, E) of its protocol round."""
+        cfg = self.cfg
+        p = _layer_slice(sub, period)
+        h = L.rmsnorm(x, p["norm1"], cfg.norm_eps)
+        if mixer == "attention":
+            a, _ = A.gqa_prefill(p["mixer"], h, cfg, causal=True)
+        else:
+            a, _ = S.mamba_mix(p["mixer"], h, cfg)
+        x = self._placed(x + a, 0)
+        return self._gate(p, x) if gate else x
+
+    def _dense_ffn_step(self, sub, period, x):
+        """A hybrid sublayer's dense SwiGLU, in situ at the query's node."""
+        p = _layer_slice(sub, period)
+        h = L.rmsnorm(x, p["norm2"], self.cfg.norm_eps)
+        return self._placed(x + L.swiglu(p["ffn"], h), 0)
+
     def _ffn_step(self, stack, layer, h):
-        return self._expert_ffn(h, _layer_slice(stack, layer))
+        # On a node mesh every token's input reaches every chip, and
+        # each chip computes its own experts' outputs.
+        ye = self._expert_ffn(self._placed(h, None),
+                              _layer_slice(stack, layer))
+        return self._placed(ye, 2)
 
     def _combine_step(self, x, ye, alpha, gates):
         """Steps 4-5: the Eq.-8 weights of the selected experts and the
         weighted sum of their outputs, added to x."""
         w = alpha * gates
         w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)   # Eq. 8
+        # On a node mesh each chip weighs its own experts' outputs; the
+        # sums return to the tokens' chips.
         y = jnp.einsum("bsed,bse->bsd", ye.astype(jnp.float32),
-                       w).astype(x.dtype)
-        return x + y
+                       self._placed(w, 2))
+        return x + self._placed(y, 0).astype(x.dtype)
 
     def _unembed_step(self, norm, table, x):
         return L.unembed(L.rmsnorm(x, norm, self.cfg.norm_eps), table)
@@ -270,21 +401,30 @@ class DMoESimulator:
             stack = self.params["stages"]["stage0"]
             rounds: List[proto.RoundAccounting] = []
             schedules: List[RoundSchedule] = []
-            hist = np.zeros((cfg.num_layers, self.k))
-            for layer in range(cfg.num_layers):
-                with TraceAnnotation("dmoe.round", layer=layer + 1,
-                                     **{"pass": self.passes}):
-                    x, rs, acct = self._round(x, stack, rates, layer)
-                    schedules.append(rs)
-                    rounds.append(acct)
-                    hist[layer] = rs.alpha.sum(axis=(0, 1)) / max(
-                        rs.alpha.sum(), 1)
+            hist = np.zeros((self.rounds_per_pass, self.k))
+
+            def record(layer, rs, acct):
+                schedules.append(rs)
+                rounds.append(acct)
+                hist[layer] = rs.alpha.sum(axis=(0, 1)) / max(
+                    rs.alpha.sum(), 1)
+
+            if self.plan is None:
+                for layer in range(cfg.num_layers):
+                    with TraceAnnotation("dmoe.round", layer=layer + 1,
+                                         **{"pass": self.passes}):
+                        x, rs, acct = self._round(x, stack, rates, layer)
+                        record(layer, rs, acct)
+            else:
+                x = self._hybrid_layers(x, stack, rates, record)
 
             with TraceAnnotation("dmoe.unembed"):
                 table = (self.params["embed"] if cfg.tie_embeddings
                          else self.params["unembed"])
                 logits = self._unembed(self.params["final_norm"], table, x)
-            span.set_metadata(compiles=self.compiles - compiles)
+            span.set_metadata(compiles=self.compiles - compiles,
+                              chips=self.chips,
+                              xchip_bytes=self.xchip_bytes(k, n))
             summary = proto.summarize(rounds)
             with TraceAnnotation("dmoe.logits_d2h"):
                 return SimResult(
@@ -304,7 +444,38 @@ class DMoESimulator:
         # -- step 2: attention + gate (in-situ) ------------------------
         with TraceAnnotation("dmoe.attn_gate"):
             x, h, gates_dev = self._attn_gate(stack, i, x)   # gates (K, N, E)
+        return self._protocol_round(x, h, gates_dev, stack, i, rates, layer)
 
+    def _hybrid_layers(self, x, stack, rates: np.ndarray, record):
+        """The hybrid layer plan over every period: each sublayer's mixer
+        step, then a protocol round (MoE sublayer; `record` takes its
+        schedule and accounting) or the in-situ dense FFN step."""
+        layer = 0
+        for period in range(self.cfg.num_layers // len(self.plan)):
+            p = np.int32(period)
+            for i, (mixer, moe) in enumerate(self.plan):
+                sub = stack[f"sub{i}"]
+                if not moe:
+                    with TraceAnnotation("dmoe.mixer", kind=mixer):
+                        x = self._mixer(sub, p, x, mixer=mixer, gate=False)
+                    with TraceAnnotation("dmoe.dense_ffn"):
+                        x = self._dense_ffn(sub, p, x)
+                    continue
+                with TraceAnnotation("dmoe.round", layer=layer + 1,
+                                     **{"pass": self.passes}):
+                    with TraceAnnotation("dmoe.mixer", kind=mixer):
+                        x, h, gates_dev = self._mixer(sub, p, x, mixer=mixer,
+                                                      gate=True)
+                    x, rs, acct = self._protocol_round(x, h, gates_dev, sub,
+                                                       p, rates, layer)
+                record(layer, rs, acct)
+                layer += 1
+        return x
+
+    def _protocol_round(self, x, h, gates_dev, stack, i, rates: np.ndarray,
+                        layer: int):
+        """Steps 3-5 of round `layer` (0-based) once the gate scores are
+        dispatched: the expert FFNs read `stack` at index `i`."""
         # -- step 3: joint expert & subcarrier allocation --------------
         # The per-expert FFN outputs don't depend on alpha (selection
         # only weights the Eq.-8 combine), so the overlap-aware loop

@@ -150,3 +150,120 @@ def test_host_sweep_matches_per_token_loop():
     np.testing.assert_array_equal(alpha, ref_alpha)
     assert nodes == ref_nodes
     assert (alpha[0, -1] == 0).all() and (alpha[2, 0] == 0).all()
+
+
+# ----------------------------------------------------------------------
+# Unreachable experts (+inf costs): exact, not `_BIG`-clamped
+# ----------------------------------------------------------------------
+
+#: Pass 4, source 3, token 30 (second round) of the Phi-3.5-MoE protocol
+#: pass at the benchmark's CPU widths, seed 11, under its JESA beta:
+#: with unreachable costs clamped to 1e15 the bound lost the 1e-3 J
+#: costs to rounding and DES chose {5, 8} at 0.015244 J; the optimum is
+#: {4, 8} at 0.014282 J.
+PHI_SEED11 = (
+    [0.006727094296365976, 0.004536804277449846, 0.0020536005031317472,
+     0.018505461513996124, 0.06061439588665962, 0.15187892317771912,
+     0.014945043250918388, 0.05448504909873009, 0.43193313479423523,
+     0.016963671892881393, 0.0757904201745987, 0.024346502497792244,
+     0.02446860633790493, 0.02785804122686386, 0.036252301186323166,
+     0.04864099994301796],
+    [0.0011319644157344474, 0.0021506947884747133, 0.0031495159314274667,
+     0.004, 0.005141353913104522, 0.0061034932010992455,
+     0.007099689924031978, 0.008152453923684846, 0.009140627624994787,
+     0.010158256116531561, 0.011110458689762712, 0.012110790966404515,
+     0.013123607361896997, np.inf, 0.015096608394645038,
+     0.016149719372006682],
+    0.48999999999999994)
+
+
+def _unreachable_case(case):
+    """(scores (B, 16), costs (B, 16), qos (B,)) of one case."""
+    if case == "phi-seed11":
+        t, e, qos = PHI_SEED11
+        return np.array([t]), np.array([e]), np.array([qos])
+    seed = int(case.rsplit("seed", 1)[1])
+    rng = np.random.default_rng(seed)
+    b, k = 6, 16
+    t = rng.dirichlet(np.full(k, 0.3), size=b)
+    e = rng.uniform(1e-3, 2e-2, size=(b, k))
+    e[rng.random((b, k)) < 0.25] = np.inf
+    qos = rng.uniform(0.3, 0.6, size=b)
+    # a row whose reachable experts cannot meet the QoS, though the two
+    # unreachable ones of highest score can: Remark 2's Top-D, priced +inf
+    top = np.argsort(-t[0])[:2]
+    e[0, top] = np.inf
+    qos[0] = t[0, top].sum() - 1e-9
+    return t, e, qos
+
+
+@pytest.mark.parametrize("case", ["phi-seed11", "k16-seed0", "k16-seed1",
+                                  "k16-seed2"])
+def test_unreachable_costs_match_enumeration(case):
+    """With some experts unreachable, the batched and the per-instance
+    DES equal the enumeration of every subset: an unreachable expert is
+    chosen only where no reachable subset meets the QoS."""
+    t, e, qos = _unreachable_case(case)
+    batch = des_lib.des_select_batch(t, e, qos, 2)
+    for i in range(t.shape[0]):
+        brute = des_lib.des_select_brute_force(t[i], e[i], float(qos[i]), 2)
+        one = des_lib.des_select(t[i], e[i], float(qos[i]), 2)
+        for res in (batch[i], one):
+            np.testing.assert_array_equal(res.selected, brute.selected)
+            assert res.feasible == brute.feasible
+            assert res.energy == brute.energy
+    _assert_batch_matches_reference(t, e, qos, 2)
+    if case == "phi-seed11":
+        assert set(np.flatnonzero(batch.selected[0])) == {4, 8}
+        assert batch.energy[0] == 0.014281981538099309
+    else:
+        assert not batch.feasible[0] and batch.energy[0] == np.inf
+
+
+def test_k8_jesa_sweep_bit_identical():
+    """A fixed K=8 JESA sweep (4 channel draws x 2 layers, N=64, the
+    Mixtral cell's QoS) keeps the selections, subcarriers, energies, B&B
+    node counts and Remark-2 rows it had with `_BIG`-clamped costs."""
+    import hashlib
+
+    from repro.core import channel as channel_lib
+    from repro.core import energy as energy_lib
+    from repro.core.gating import QoSSchedule
+    from repro.schedulers import ScheduleContext, get_policy
+
+    solved = des_lib.des_select_batch
+    fallback = []
+
+    def counting(*args, **kwargs):
+        res = solved(*args, **kwargs)
+        fallback.append(int((~res.feasible).sum()))
+        return res
+
+    digest, nodes = hashlib.sha256(), 0
+    k, n = 8, 64
+    rng = np.random.default_rng(20150)
+    ch = channel_lib.ChannelConfig(num_experts=k, num_subcarriers=64)
+    qos = QoSSchedule(z=1.0, gamma0=0.7)
+    des_lib.des_select_batch = counting
+    try:
+        for _ in range(4):
+            rates = channel_lib.subcarrier_rates(
+                ch, channel_lib.sample_channel_gains(ch, rng))
+            for layer in (1, 2):
+                logits = rng.normal(size=(k, n, k)) * 1.5
+                gates = np.exp(logits) / np.exp(logits).sum(-1, keepdims=True)
+                ctx = ScheduleContext(
+                    gate_scores=gates, rates=rates, layer=layer,
+                    qos=qos.qos(layer), qos_schedule=qos, max_experts=2,
+                    top_k=2, comp_coeff=energy_lib.make_comp_coeffs(k),
+                    rng=rng)
+                rs = get_policy("jesa").schedule(ctx)
+                for a in (rs.alpha, rs.beta, np.asarray(rs.energy_trace)):
+                    digest.update(np.ascontiguousarray(a).tobytes())
+                nodes += rs.des_nodes
+    finally:
+        des_lib.des_select_batch = solved
+    assert digest.hexdigest() == (
+        "0bc6e720283a793356d87f8380466d711305a2aad99cf21d51b4f09daf73dfb6")
+    assert nodes == 184825
+    assert (sum(fallback), len(fallback)) == (3831, 24)

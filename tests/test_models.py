@@ -99,6 +99,19 @@ def test_decode_consistency_jamba():
                       capacity_factor=8.0)), atol=1e-3)
 
 
+def test_decode_consistency_jamba_no_rope_inner_norms():
+    """Jamba as published: no rotary positions, RMSNorms on Mamba's dt,
+    B and C; the cached decode applies both as the full pass does."""
+    _consistency(ModelConfig(
+        arch_type="hybrid", num_layers=8, d_model=64, num_heads=4,
+        num_kv_heads=2, d_ff=128, vocab_size=128, dtype="float32",
+        param_dtype="float32", rope=False, norm_eps=1e-6,
+        ssm=SSMConfig(kind="mamba", d_state=8, attn_every=8,
+                      inner_norms=True),
+        moe=MoEConfig(num_experts=4, top_k=2, d_ff_expert=64, every=2,
+                      capacity_factor=8.0)), atol=1e-3)
+
+
 def test_sliding_window_consistency():
     cfg = ModelConfig(arch_type="dense", num_layers=2, d_model=64,
                       num_heads=4, num_kv_heads=2, d_ff=128, vocab_size=128,

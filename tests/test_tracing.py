@@ -143,3 +143,39 @@ def test_served_pass_spans(cfg, scheme, tmp_path):
     rows = ctx.gate_scores.shape[0] * ctx.gate_scores.shape[1]
     assert 0 < fallback < rows * steps
     assert sum(s[3]["fallback"] for s in des) == fallback
+
+
+def test_hybrid_pass_spans(tmp_path):
+    """The Jamba plan's spans: `dmoe.mixer` (kind mamba or attention) and
+    `dmoe.dense_ffn` at every sublayer that is no protocol round, the
+    mixer inside `dmoe.round` where it is one; `dmoe.pass` carries the
+    chips and the bytes moved between them (none on one device)."""
+    cfg = get_smoke_config("jamba2-mini")
+    sim = DMoESimulator(cfg, scheme="jesa", seed=2)
+    tokens = np.random.default_rng(3).integers(0, cfg.vocab_size, (4, 8))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        sim.serve(tokens)
+    finally:
+        jax.profiler.stop_trace()
+    (line,) = [line for line in _span_lines(tmp_path)
+               if any(s[0] == "dmoe.pass" for s in line)]
+    by_name = {}
+    for s in line:
+        by_name.setdefault(s[0], []).append(s)
+    (p,) = by_name["dmoe.pass"]
+    assert p[3]["chips"] == 1 and p[3]["xchip_bytes"] == 0
+    mixers = sorted(by_name["dmoe.mixer"], key=lambda s: s[1])
+    assert [m[3]["kind"] for m in mixers] == [
+        "attention" if i == 4 else "mamba" for i in range(8)]
+    parents = [_parent(m, line)[0] for m in mixers]
+    assert parents == ["dmoe.pass", "dmoe.round"] * 4
+    assert len(by_name["dmoe.dense_ffn"]) == 4
+    assert all(_parent(s, line)[0] == "dmoe.pass"
+               for s in by_name["dmoe.dense_ffn"])
+    assert [r[3]["layer"] for r in by_name["dmoe.round"]] == [1, 2, 3, 4]
+    assert "dmoe.attn_gate" not in by_name and "dmoe.params" not in by_name
+    for name in ("dmoe.expert_ffn", "dmoe.gate_d2h", "dmoe.schedule",
+                 "dmoe.combine", "dmoe.account"):
+        assert len(by_name[name]) == 4
+        assert all(_parent(s, line)[0] == "dmoe.round" for s in by_name[name])
