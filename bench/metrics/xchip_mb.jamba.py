@@ -1,7 +1,11 @@
-"""Megabytes of hidden states and expert outputs a protocol pass of the
-Jamba cell moves between chips: the metadata `xchip_bytes` of the
-program's `dmoe.pass` span, which the simulator counts from the shapes
-and the node mesh, mean per pass of the traced window."""
+"""Megabytes of the protocol's step-4 and step-5 payload a pass of the
+Jamba cell sends between chips: the metadata `xchip_bytes` of the
+program's `dmoe.pass` span, mean per pass of the traced window.  The
+simulator counts it from the shapes and the node mesh (each round's FFN
+input to the other chips, each chip's float32 expert sum back), so it
+is the protocol's nominal payload, fixed by the configuration, and not
+the bytes the compiled collectives move: `xchip_ms.jamba` reads those
+from the device trace."""
 
 import program_spans
 

@@ -252,11 +252,13 @@ class DMoESimulator:
             self.mesh, jax.sharding.PartitionSpec(*spec)))
 
     def xchip_bytes(self, k: int, n: int) -> int:
-        """Bytes a pass of (k, n) tokens moves between chips, from the
-        shapes and the mesh: each round's FFN input (activation dtype)
-        goes from its token's chip to every other chip, and each chip's
-        float32 sum of its experts' weighted outputs goes back to every
-        other chip's tokens."""
+        """The protocol's step-4 and step-5 payload between chips for a
+        pass of (k, n) tokens, from the shapes and the mesh: each round's
+        FFN input (activation dtype) goes from its token's chip to every
+        other chip, and each chip's float32 sum of its experts' weighted
+        outputs goes back to every other chip's tokens.  A nominal
+        count: the collectives XLA compiles for it may move more (an
+        all-reduce for the return, for one)."""
         if self.mesh is None:
             return 0
         act = 4 if self.cfg.dtype == "float32" else 2
@@ -280,11 +282,18 @@ class DMoESimulator:
         """Step 2 (in-situ): the layer's attention and gate.  Returns x
         after attention, the FFN's input h and the gate scores
         (K, N, E)."""
-        cfg = self.cfg
         p = _layer_slice(stack, layer)
-        h = L.rmsnorm(x, p["norm1"], cfg.norm_eps)
-        a, _ = A.gqa_prefill(p["attn"], h, cfg, causal=True)
-        return self._gate(p, x + a)
+        return self._gate(p, self._mix(p, p["attn"], x, "attention"))
+
+    def _mix(self, p, weights, x, mixer: str):
+        """RMSNorm, the mixer (attention, with no rotary positions where
+        the config has none, or Mamba) on its `weights`, the residual."""
+        h = L.rmsnorm(x, p["norm1"], self.cfg.norm_eps)
+        if mixer == "attention":
+            a, _ = A.gqa_prefill(weights, h, self.cfg, causal=True)
+        else:
+            a, _ = S.mamba_mix(weights, h, self.cfg)
+        return self._placed(x + a, 0)
 
     def _gate(self, p, x):
         """The FFN's norm and the router's softmax: (x, h, gates)."""
@@ -294,18 +303,11 @@ class DMoESimulator:
         return x, h, jax.nn.softmax(logits, axis=-1)
 
     def _mixer_step(self, sub, period, x, *, mixer: str, gate: bool):
-        """A hybrid sublayer's mixer (in situ): RMSNorm, Mamba or
-        attention (no rotary positions where the config has none), the
-        residual; with `gate`, also the FFN's norm and the gate scores
-        (K, N, E) of its protocol round."""
-        cfg = self.cfg
+        """A hybrid sublayer's mixer (in situ, `_mix`); with `gate`, also
+        the FFN's norm and the gate scores (K, N, E) of its protocol
+        round."""
         p = _layer_slice(sub, period)
-        h = L.rmsnorm(x, p["norm1"], cfg.norm_eps)
-        if mixer == "attention":
-            a, _ = A.gqa_prefill(p["mixer"], h, cfg, causal=True)
-        else:
-            a, _ = S.mamba_mix(p["mixer"], h, cfg)
-        x = self._placed(x + a, 0)
+        x = self._mix(p, p["mixer"], x, mixer)
         return self._gate(p, x) if gate else x
 
     def _dense_ffn_step(self, sub, period, x):

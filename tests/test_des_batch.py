@@ -176,15 +176,24 @@ PHI_SEED11 = (
      0.016149719372006682],
     0.48999999999999994)
 
+#: A K=8 row whose only QoS-meeting subsets hold an unreachable expert:
+#: no pair of reachable experts reaches 0.48, {0, 2} and {0, 1} do.  The
+#: `_BIG` clamp priced {0, 2} at 1e15 + 2e-3 J and chose it as feasible;
+#: the row is now Remark 2's Top-D, {0, 1}, priced +inf.
+K8_REMARK2 = (
+    [0.40, 0.35, 0.10, 0.05, 0.04, 0.03, 0.02, 0.01],
+    [np.inf, np.inf, 2e-3, 3e-3, 4e-3, 5e-3, 6e-3, 7e-3],
+    0.48)
+
 
 def _unreachable_case(case):
-    """(scores (B, 16), costs (B, 16), qos (B,)) of one case."""
+    """(scores (B, K), costs (B, K), qos (B,)) of one case."""
     if case == "phi-seed11":
         t, e, qos = PHI_SEED11
         return np.array([t]), np.array([e]), np.array([qos])
     seed = int(case.rsplit("seed", 1)[1])
     rng = np.random.default_rng(seed)
-    b, k = 6, 16
+    b, k = 6, int(case[1:].split("-")[0])
     t = rng.dirichlet(np.full(k, 0.3), size=b)
     e = rng.uniform(1e-3, 2e-2, size=(b, k))
     e[rng.random((b, k)) < 0.25] = np.inf
@@ -194,15 +203,32 @@ def _unreachable_case(case):
     top = np.argsort(-t[0])[:2]
     e[0, top] = np.inf
     qos[0] = t[0, top].sum() - 1e-9
+    if k == 8:
+        t[0], e[0], qos[0] = K8_REMARK2
     return t, e, qos
 
 
+def _clamped_optimum(t, e, qos, d):
+    """The cheapest subset of at most `d` experts meeting `qos` with
+    every +inf cost clamped to `_BIG`: what DES chose before it priced
+    unreachable experts."""
+    import itertools
+
+    e = np.minimum(np.where(np.isfinite(e), e, des_lib._BIG), des_lib._BIG)
+    subsets = [s for r in range(1, d + 1)
+               for s in itertools.combinations(range(len(t)), r)
+               if sum(t[list(s)]) >= qos]
+    return set(min(subsets, key=lambda s: e[list(s)].sum()))
+
+
 @pytest.mark.parametrize("case", ["phi-seed11", "k16-seed0", "k16-seed1",
-                                  "k16-seed2"])
+                                  "k16-seed2", "k8-seed3"])
 def test_unreachable_costs_match_enumeration(case):
     """With some experts unreachable, the batched and the per-instance
     DES equal the enumeration of every subset: an unreachable expert is
-    chosen only where no reachable subset meets the QoS."""
+    chosen only where no reachable subset meets the QoS, and there the
+    row is Remark 2's Top-D priced +inf.  At K=8 that changes the
+    selection of such a row from what the `_BIG` clamp chose."""
     t, e, qos = _unreachable_case(case)
     batch = des_lib.des_select_batch(t, e, qos, 2)
     for i in range(t.shape[0]):
@@ -218,12 +244,18 @@ def test_unreachable_costs_match_enumeration(case):
         assert batch.energy[0] == 0.014281981538099309
     else:
         assert not batch.feasible[0] and batch.energy[0] == np.inf
+    if case == "k8-seed3":
+        assert set(np.flatnonzero(batch.selected[0])) == {0, 1}
+        assert _clamped_optimum(t[0], e[0], qos[0], 2) == {0, 2}
 
 
 def test_k8_jesa_sweep_bit_identical():
     """A fixed K=8 JESA sweep (4 channel draws x 2 layers, N=64, the
     Mixtral cell's QoS) keeps the selections, subcarriers, energies, B&B
-    node counts and Remark-2 rows it had with `_BIG`-clamped costs."""
+    node counts and Remark-2 rows it had with `_BIG`-clamped costs.  It
+    holds no unreachable expert.  Where one occurs, B&B node counts fall
+    (the bound keeps the costs' last bits), and a row whose only
+    QoS-meeting subsets need one changes selection (`K8_REMARK2`)."""
     import hashlib
 
     from repro.core import channel as channel_lib

@@ -200,6 +200,20 @@ def test_cache_differentiates_max_experts():
     _assert_same_answer(warm3, cold3)
 
 
+def test_cache_hits_rows_stored_from_a_larger_batch():
+    """A row stored from one batch hits when looked up alone: its hash
+    does not depend on the other rows it was stored with (a matrix
+    product's rounding can depend on the number of rows)."""
+    t, e, qos = _batch(29, 64, 16, with_inf=False)
+    cache = WarmStartCache()
+    des_select_batch(t, e, qos, 2, warm_cache=cache)
+    for i in range(64):
+        one = des_select_batch(t[i:i + 1], e[i:i + 1], qos[i:i + 1], 2,
+                               warm_cache=cache)
+        assert one.nodes_explored[0] == 0, i
+    assert cache.stats["exact_hits"] == 64
+
+
 def test_cache_eviction_keeps_answers():
     """Overflowing max_entries evicts wholesale but never corrupts: the
     steady-state footprint is bounded by one call's working set (at most
