@@ -7,10 +7,9 @@ subcarrier (Eq. 16), turning P3 into a weighted bipartite assignment:
     links (i, j) with s_ij > 0   x   subcarriers m
     edge weight w_ij^(m) = P0 * s_ij / r_ij^(m)
 
-solved optimally in polynomial time (Kuhn-Munkres / Hungarian).  scipy is
-not available offline, so we implement the shortest-augmenting-path
-Hungarian algorithm (Jonker-Volgenant style, the same algorithm behind
-scipy.optimize.linear_sum_assignment) in numpy.
+solved optimally in polynomial time (Kuhn-Munkres / Hungarian), here by
+scipy.optimize.linear_sum_assignment (shortest augmenting paths,
+Jonker-Volgenant style, compiled).
 
 Fast path (Theorem 1's event A): if every active link's best subcarrier
 (argmax_m r_ij^(m)) is distinct, assigning each link its own best
@@ -19,9 +18,10 @@ subcarrier is optimal regardless of s_ij — no Hungarian needed.
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import NamedTuple, Tuple
 
 import numpy as np
+from scipy import optimize
 
 _INF = 1e30
 
@@ -29,53 +29,14 @@ _INF = 1e30
 def linear_sum_assignment(cost: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     """Minimum-cost rectangular assignment (rows <= cols).
 
-    Returns (row_idx, col_idx) like scipy's linear_sum_assignment.
-    Shortest-augmenting-path with potentials; O(n^2 m).
+    Returns (row_idx, col_idx), rows ascending, from
+    scipy.optimize.linear_sum_assignment.
     """
     cost = np.asarray(cost, dtype=np.float64)
     n, m = cost.shape
     if n > m:
         raise ValueError(f"need rows <= cols, got {cost.shape}")
-
-    u = np.zeros(n + 1)
-    v = np.zeros(m + 1)
-    p = np.zeros(m + 1, dtype=np.int64)   # p[j]: row (1-based) matched to col j
-    way = np.zeros(m + 1, dtype=np.int64)
-
-    for i in range(1, n + 1):
-        p[0] = i
-        j0 = 0
-        minv = np.full(m + 1, np.inf)
-        used = np.zeros(m + 1, dtype=bool)
-        while True:
-            used[j0] = True
-            i0 = p[j0]
-            # vectorized relaxation over unused columns
-            cols = np.nonzero(~used[1:])[0] + 1
-            cur = cost[i0 - 1, cols - 1] - u[i0] - v[cols]
-            better = cur < minv[cols]
-            minv[cols] = np.where(better, cur, minv[cols])
-            way[cols[better]] = j0
-            jt = cols[np.argmin(minv[cols])]
-            delta = minv[jt]
-            # update potentials
-            u[p[used]] += delta
-            v[used] -= delta
-            minv[~used] -= delta
-            j0 = jt
-            if p[j0] == 0:
-                break
-        # augment along the alternating path
-        while j0:
-            j1 = way[j0]
-            p[j0] = p[j1]
-            j0 = j1
-
-    row_of_col = p[1:]  # 1-based rows, 0 = unmatched
-    cols = np.nonzero(row_of_col > 0)[0]
-    rows = row_of_col[cols] - 1
-    order = np.argsort(rows)
-    return rows[order], cols[order]
+    return optimize.linear_sum_assignment(cost)
 
 
 def max_rate_assignment(rates: np.ndarray, links: np.ndarray) -> np.ndarray | None:
@@ -86,10 +47,19 @@ def max_rate_assignment(rates: np.ndarray, links: np.ndarray) -> np.ndarray | No
       links: (L, 2) int array of active (i, j) links.
     Returns (L,) chosen subcarriers or None if a collision exists.
     """
-    best = np.array([int(np.argmax(rates[i, j])) for i, j in links])
+    best = np.argmax(rates[links[:, 0], links[:, 1]], axis=-1)
     if len(np.unique(best)) != len(best):
         return None
     return best
+
+
+class Assignment(NamedTuple):
+    """A solved P3(a): beta, the links it serves, and whether the
+    assignment solver ran (False: the fast path, greedy, or no links)."""
+
+    beta: np.ndarray
+    links: int
+    solver: bool
 
 
 def allocate_subcarriers(
@@ -118,13 +88,27 @@ def allocate_subcarriers(
       strict: raise ValueError on C3-infeasible traffic instead of
         serving the top-M links.
     """
+    return assign_subcarriers(s_bytes, rates, p0, method=method,
+                              strict=strict).beta
+
+
+def assign_subcarriers(
+    s_bytes: np.ndarray,
+    rates: np.ndarray,
+    p0: float,
+    *,
+    method: str = "auto",
+    strict: bool = False,
+) -> Assignment:
+    """`allocate_subcarriers`, with the number of links served and
+    whether `linear_sum_assignment` solved it."""
     k, _, m = rates.shape
     beta = np.zeros((k, k, m), dtype=np.int8)
     off_diag = ~np.eye(k, dtype=bool)
     links = np.argwhere(off_diag & (s_bytes > 0))
     n_links = len(links)
     if n_links == 0:
-        return beta
+        return Assignment(beta, 0, False)
     if n_links > m:
         if strict:
             raise ValueError(
@@ -139,9 +123,8 @@ def allocate_subcarriers(
     if method == "auto":
         fast = max_rate_assignment(rates, links)
         if fast is not None:
-            for (i, j), sc in zip(links, fast):
-                beta[i, j, sc] = 1
-            return beta
+            beta[links[:, 0], links[:, 1], fast] = 1
+            return Assignment(beta, n_links, False)
         method = "hungarian"
 
     if method == "greedy":
@@ -154,21 +137,18 @@ def allocate_subcarriers(
             sc = int(np.argmax(r))
             beta[i, j, sc] = 1
             free[sc] = False
-        return beta
+        return Assignment(beta, n_links, False)
 
     if method != "hungarian":
         raise ValueError(f"unknown method {method!r}")
 
-    w = np.empty((n_links, m), dtype=np.float64)
-    for li, (i, j) in enumerate(links):
-        r = rates[i, j]
-        with np.errstate(divide="ignore"):
-            w[li] = np.where(r > 0, p0 * s_bytes[i, j] / r, _INF)
+    r = rates[links[:, 0], links[:, 1]]
+    s = s_bytes[links[:, 0], links[:, 1], None]
+    with np.errstate(divide="ignore"):
+        w = np.where(r > 0, p0 * s / r, _INF)
     rows, cols = linear_sum_assignment(w)
-    for li, sc in zip(rows, cols):
-        i, j = links[li]
-        beta[i, j, sc] = 1
-    return beta
+    beta[links[rows, 0], links[rows, 1], cols] = 1
+    return Assignment(beta, n_links, True)
 
 
 def assignment_energy(

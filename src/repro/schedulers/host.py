@@ -38,12 +38,19 @@ def _round_energy(alpha: np.ndarray, beta: np.ndarray, ctx: ScheduleContext
 
 def _allocate_beta(alpha: np.ndarray, ctx: ScheduleContext,
                    beta_method: str) -> np.ndarray:
-    """Optimal subcarrier assignment for the traffic implied by alpha."""
-    with TraceAnnotation("dmoe.assign"):
+    """Optimal subcarrier assignment for the traffic implied by alpha.
+
+    The `dmoe.assign` profiler span, whose metadata `links` (active
+    links solved) and `solver` (1 if `linear_sum_assignment` solved it,
+    0 for the Theorem-1 fast path, greedy, or no links) it sets when it
+    ends."""
+    with TraceAnnotation("dmoe.assign") as span:
         s_bytes = ctx.s0 * alpha.sum(axis=1).astype(np.float64)
         np.fill_diagonal(s_bytes, 0.0)  # in-situ: no transmission
-        return sc_lib.allocate_subcarriers(s_bytes, ctx.rates, ctx.p0,
-                                           method=beta_method)
+        res = sc_lib.assign_subcarriers(s_bytes, ctx.rates, ctx.p0,
+                                        method=beta_method)
+        span.set_metadata(links=res.links, solver=int(res.solver))
+    return res.beta
 
 
 def _des_sweep(gate_scores: np.ndarray, costs: np.ndarray, qos: float,

@@ -299,3 +299,56 @@ def test_k8_jesa_sweep_bit_identical():
         "0bc6e720283a793356d87f8380466d711305a2aad99cf21d51b4f09daf73dfb6")
     assert nodes == 184825
     assert (sum(fallback), len(fallback)) == (3831, 24)
+
+
+def test_k16_jesa_sweep_bit_identical():
+    """A fixed K=16 JESA sweep at the Jamba cell's shape (M=240
+    subcarriers, 16 tokens a source, 2 channel draws x 2 layers, the
+    cell's QoS) keeps its selections, subcarriers, energies and B&B node
+    count.  The digest and counts were recorded with the shortest-
+    augmenting-path Hungarian written in numpy that `linear_sum_assignment`
+    used before it called scipy's solver, so they pin the compiled
+    solver to the same assignments over 200-206 active links."""
+    import hashlib
+
+    from repro.core import channel as channel_lib
+    from repro.core import energy as energy_lib
+    from repro.core.gating import QoSSchedule
+    from repro.schedulers import ScheduleContext, get_policy
+
+    solved = des_lib.des_select_batch
+    fallback = []
+
+    def counting(*args, **kwargs):
+        res = solved(*args, **kwargs)
+        fallback.append(int((~res.feasible).sum()))
+        return res
+
+    digest, nodes = hashlib.sha256(), 0
+    k, n = 16, 16
+    rng = np.random.default_rng(20160)
+    ch = channel_lib.ChannelConfig(num_experts=k, num_subcarriers=240)
+    qos = QoSSchedule(z=1.0, gamma0=0.7)
+    des_lib.des_select_batch = counting
+    try:
+        for _ in range(2):
+            rates = channel_lib.subcarrier_rates(
+                ch, channel_lib.sample_channel_gains(ch, rng))
+            for layer in (1, 2):
+                logits = rng.normal(size=(k, n, k)) * 1.5
+                gates = np.exp(logits) / np.exp(logits).sum(-1, keepdims=True)
+                ctx = ScheduleContext(
+                    gate_scores=gates, rates=rates, layer=layer,
+                    qos=qos.qos(layer), qos_schedule=qos, max_experts=2,
+                    top_k=2, comp_coeff=energy_lib.make_comp_coeffs(k),
+                    rng=rng)
+                rs = get_policy("jesa").schedule(ctx)
+                for a in (rs.alpha, rs.beta, np.asarray(rs.energy_trace)):
+                    digest.update(np.ascontiguousarray(a).tobytes())
+                nodes += rs.des_nodes
+    finally:
+        des_lib.des_select_batch = solved
+    assert digest.hexdigest() == (
+        "3469b8564a680d14ced74ad9f810e72d4b0e8d304e0ac57ad7568ad4551561c5")
+    assert nodes == 84849
+    assert (sum(fallback), len(fallback)) == (1708, 11)

@@ -1,6 +1,8 @@
 """Subcarrier allocation (P3): Hungarian optimality, fast path, C3."""
 
+import importlib.util
 import itertools
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -8,6 +10,17 @@ from _hyp_compat import given, settings, st
 
 from repro.core import channel as channel_lib
 from repro.core import subcarrier as sc_lib
+
+
+def _schedule_ref():
+    """The benchmark's plain schedule checks (they import nothing of the
+    program), for their independent Hungarian."""
+    path = (Path(__file__).resolve().parents[1] / "bench" / "reference"
+            / "schedule_ref.py")
+    spec = importlib.util.spec_from_file_location("schedule_ref", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def _brute_force_assignment(cost):
@@ -108,3 +121,50 @@ def test_too_many_links_serves_top_m_by_bytes():
     assert served == set(map(tuple, links[order]))
     # unserved traffic -> +inf objective, never an exception
     assert sc_lib.assignment_energy(s, rates, beta, cfg.tx_power_w) == np.inf
+
+
+@pytest.mark.parametrize("k, m, seed, fast", [
+    (4, 64, 0, True),      # Theorem-1 fast path applies
+    (4, 64, 4, False),
+    (8, 64, 0, False),
+    (8, 64, 1, False),
+    (8, 32, 0, False),     # 45 links > M: the top-M cut
+    (16, 240, 0, False),
+    (16, 240, 1, False),
+    (16, 160, 0, False),   # 188 links > M: the top-M cut
+], ids=lambda v: str(v))
+def test_assignment_matches_plain_hungarian(k, m, seed, fast):
+    """`method="auto"` and `method="hungarian"` give the same beta, and
+    the served links' energy is the optimum of the plain Hungarian in
+    `bench/reference/schedule_ref.py` over the same weights (the top-M
+    links by bytes where more links are active than subcarriers)."""
+    cfg = channel_lib.ChannelConfig(num_experts=k, num_subcarriers=m)
+    rng = np.random.default_rng(seed)
+    rates = channel_lib.subcarrier_rates(
+        cfg, channel_lib.sample_channel_gains(cfg, rng))
+    s = rng.integers(1, 65, size=(k, k)) * 8192.0
+    s[rng.random((k, k)) < 0.25] = 0.0
+    np.fill_diagonal(s, 0.0)
+    p0 = cfg.tx_power_w
+
+    links = np.argwhere(~np.eye(k, dtype=bool) & (s > 0))
+    if len(links) > m:
+        top = np.argsort(-s[links[:, 0], links[:, 1]], kind="stable")[:m]
+        links = links[np.sort(top)]
+    assert (sc_lib.max_rate_assignment(rates, links) is not None) == fast
+
+    auto = sc_lib.assign_subcarriers(s, rates, p0, method="auto")
+    hung = sc_lib.assign_subcarriers(s, rates, p0, method="hungarian")
+    np.testing.assert_array_equal(auto.beta, hung.beta)
+    assert (auto.links, auto.solver) == (len(links), not fast)
+    assert (hung.links, hung.solver) == (len(links), True)
+    channel_lib.validate_beta(hung.beta)
+    served = np.argwhere(hung.beta.sum(axis=-1) > 0)
+    np.testing.assert_array_equal(served, links)
+
+    w = np.empty((len(links), m))
+    for li, (i, j) in enumerate(links):
+        w[li] = p0 * s[i, j] / rates[i, j]
+    got = sum(w[li, np.argmax(hung.beta[i, j])]
+              for li, (i, j) in enumerate(links))
+    assert got == pytest.approx(_schedule_ref().hungarian(w), rel=1e-12)

@@ -1,7 +1,7 @@
 """The served protocol pass's profiler spans (`dmoe.*`): one `dmoe.pass`
 per `DMoESimulator.serve`, every span nested where docs/serving.md says,
-the counters on `dmoe.des` equal to what the schedules report, and a
-traced pass bit-identical to an untraced one."""
+the counters on `dmoe.des` and `dmoe.assign` equal to what the
+schedules report, and a traced pass bit-identical to an untraced one."""
 
 import warnings
 
@@ -11,6 +11,7 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro.configs.base import get_smoke_config
+from repro.core import subcarrier as sc_lib
 from repro.core.gating import QoSSchedule
 from repro.schedulers import SchedulerPolicy, get_policy
 from repro.serving import DMoESimulator
@@ -130,9 +131,20 @@ def test_served_pass_spans(cfg, scheme, tmp_path):
     steps = sum(rs.iterations for rs in schedules)
     des = [s for s, _ in spans if s[0] == "dmoe.des"]
     assert len(des) == steps
-    assert sum(1 for s, _ in spans if s[0] == "dmoe.assign") == steps
+    assign = sorted((s for s, _ in spans if s[0] == "dmoe.assign"),
+                    key=lambda s: s[1])
+    assert len(assign) == steps
     assert sum(s[3]["nodes"] for s in des) == sum(
         rs.des_nodes for rs in schedules)
+
+    # A round's last assignment is its beta: `links` counts the links it
+    # serves, `solver` is 0 where Theorem 1's fast path took them.
+    last = np.cumsum([rs.iterations for rs in schedules]) - 1
+    for ctx, rs, i in zip(policy.contexts, schedules, last, strict=True):
+        links = np.argwhere(rs.beta.sum(axis=-1) > 0)
+        fast = sc_lib.max_rate_assignment(ctx.rates, links) is not None
+        assert assign[i][3] == {"links": len(links), "solver": int(not fast)}
+    assert {s[3]["solver"] for s in assign} <= {0, 1}
 
     # Remark-2 rows, counted apart from the solver: no D experts reach
     # the round's QoS.  Each alpha step of the round solves them again.
