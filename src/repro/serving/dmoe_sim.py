@@ -18,27 +18,26 @@ The model math is exact (the simulator produces the same logits a
 centralized run with the same per-token expert masks would); what is
 simulated is the wireless channel + energy, not the transformer.
 
-Compiled round steps: the device work of a round is three jitted steps,
-built once per simulator -- attention + gate, every expert's FFN, and
-the Eq.-8 combine -- with embed and unembed jitted beside them.  Each
-step slices its layer's weights from the stacked params by a traced
-layer index, so one executable serves every layer, and jit's own cache
-keys them on the (K, N) shape.  The gate step and the FFN step are
-separate executables on purpose: an executable's outputs are ready
-only when all of it ends, so a fused step would hold the gate scores
-back until the FFN were done.
-
-Hybrid blocks (Jamba, `arch_type="hybrid"`): the simulator walks the
-period's layer plan (`models.transformer.jamba_sublayers`).  Every
+One layer plan: the simulator walks `plan`, the (mixer kind, protocol
+round?) of each sublayer of a period, over every period of the stack.
+A plain MoE block (`arch_type="moe"`) is a period of one sublayer,
+attention with a protocol round; a hybrid (Jamba, `arch_type="hybrid"`)
+takes its period from `models.transformer.jamba_sublayers`.  Every
 sublayer runs one jitted mixer step -- RMSNorm, Mamba or attention, the
 residual -- and at a MoE sublayer the FFN's norm and the gate with it.
 A MoE sublayer is then one protocol round as above (the QoS l counts
 rounds); a dense sublayer's SwiGLU runs in situ at the query's node in
 its own jitted step, with no scheduler call and no transmission.  Every
 node holds the shared blocks (Eq. 6): mixers and dense FFNs included.
-The steps read the stacked period weights by a traced period index and
-take the sublayer's subtree, so one executable serves every period and
-every sublayer of one shape.
+
+Compiled steps: embed, the mixer step, every expert's FFN, the Eq.-8
+combine, the dense FFN and unembed are each jitted once per simulator.
+Each step slices its period's weights from the stacked params by a
+traced period index, so one executable serves every period and every
+sublayer of one shape, and jit's own cache keys them on the (K, N)
+shape.  The gated mixer step and the FFN step are separate executables
+on purpose: an executable's outputs are ready only when all of it ends,
+so a fused step would hold the gate scores back until the FFN were done.
 
 Nodes over chips: with a 1-D device `mesh` (its one axis is the edge
 node), each chip holds K / chips nodes -- their queries' hidden states
@@ -50,14 +49,13 @@ outputs return to the tokens' chips (step 5).  The scheduler stays one
 global host policy.  With no mesh, or a mesh of one device, nothing
 is placed or constrained.
 
-Overlap-aware round loop: the expert FFN einsums are dense in the expert
+Overlap-aware round: the expert FFN einsums are dense in the expert
 axis and independent of the selection alpha (alpha only weights the
-Eq.-8 combine), so with ``overlap=True`` (the default) they are
-dispatched *before* the host scheduler runs — jax's asynchronous
-dispatch overlaps the device FFN work of round l with the host
-branch-and-bound of round l (and, under the "async-des" policy, with its
-pipelined pre-work rounds).  Pure wall-clock reordering: logits, energy
-accounting, and schedules are unchanged bit for bit.
+Eq.-8 combine), so they are dispatched *before* the round waits for its
+gate scores and runs the host scheduler -- jax's asynchronous dispatch
+overlaps the device FFN work of round l with the host branch-and-bound
+of round l (and, under the "async-des" policy, with its pipelined
+pre-work rounds).
 """
 
 from __future__ import annotations
@@ -145,8 +143,7 @@ class DMoESimulator:
                  channel_process: Optional[
                      channel_lib.ChannelProcess] = None,
                  seed: int = 0, top_k: Optional[int] = None,
-                 count_backward: bool = True, overlap: bool = True,
-                 routing_impl: str = "xla",
+                 count_backward: bool = True,
                  mesh: Optional[jax.sharding.Mesh] = None):
         assert cfg.moe.num_experts >= 1
         assert cfg.arch_type == "moe" or (
@@ -155,9 +152,10 @@ class DMoESimulator:
         assert not cfg.mla, "simulator uses the plain GQA MoE block"
         self.cfg = cfg
         self.k = cfg.moe.num_experts
-        #: (mixer kind, protocol round?) per sublayer of a hybrid period
+        #: (mixer kind, protocol round?) per sublayer of a period; a plain
+        #: MoE block is a period of one gated attention sublayer
         self.plan = (T.jamba_sublayers(cfg) if cfg.arch_type == "hybrid"
-                     else None)
+                     else [("attention", True)])
         self.mesh = mesh if mesh is not None and mesh.size > 1 else None
         if self.mesh is not None:
             assert len(self.mesh.axis_names) == 1, "a 1-D node mesh"
@@ -167,13 +165,12 @@ class DMoESimulator:
         #: backend), never per call
         self.compiles = 0
         self._embed = self._jit(self._embed_step)
-        self._attn_gate = self._jit(self._attn_gate_step)
+        self._ffn = self._jit(self._ffn_step)
         self._combine = self._jit(self._combine_step)
         self._unembed = self._jit(self._unembed_step)
         self._mixer = self._jit(self._mixer_step,
                                 static_argnames=("mixer", "gate"))
         self._dense_ffn = self._jit(self._dense_ffn_step)
-        self.routing_impl = routing_impl     # validates; builds the FFN step
         # `scheme` is any registry name; a pre-constructed policy instance
         # (with custom kwargs) may be passed directly instead.
         self.policy = policy if policy is not None else get_policy(scheme)
@@ -196,38 +193,10 @@ class DMoESimulator:
         self.s0 = 8192.0
         self.top_k = top_k or cfg.moe.top_k
         self.count_backward = count_backward
-        # Dispatch the alpha-independent expert FFN einsums before the
-        # host scheduler each round (see module docstring); disable to
-        # serialize device and host work (e.g. for profiling them apart).
-        self.overlap = overlap
         #: served passes so far; the `pass` id of each pass's spans
         self.passes = 0
 
     # ------------------------------------------------------------------
-    @property
-    def routing_impl(self) -> str:
-        """Expert-FFN compute backend: "xla" keeps the historical dense
-        einsums bit for bit; "fused" routes the same dense all-expert
-        compute through the Pallas `repro.kernels.ops.moe_expert_ffn`
-        kernel.  "grouped" is rejected — the protocol computes every
-        expert's FFN for every token (the alpha-independent overlap
-        trick above), so there is no ragged token→expert assignment to
-        lay out.  Settable after construction (`ServingFrontend` does)."""
-        return self._routing_impl
-
-    @routing_impl.setter
-    def routing_impl(self, impl: str) -> None:
-        if impl not in ("xla", "fused"):
-            from repro.kernels.moe_route import check_routing_impl
-            check_routing_impl(impl)   # unknown name → ValueError
-            raise ValueError(
-                "DMoESimulator computes the dense all-expert FFN (alpha-"
-                "independent overlap); routing_impl must be 'xla' or "
-                f"'fused', got {impl!r}")
-        self._routing_impl = impl
-        # A fresh jit: what the old one traced ran the other backend.
-        self._ffn = self._jit(self._ffn_step)
-
     def _jit(self, step, static_argnames=()):
         """`jax.jit` of a step that counts its own traces in `compiles`."""
         @functools.wraps(step)
@@ -267,8 +236,6 @@ class DMoESimulator:
 
     @property
     def rounds_per_pass(self) -> int:
-        if self.plan is None:
-            return self.cfg.num_layers
         return (self.cfg.num_layers // len(self.plan)) * sum(
             moe for _, moe in self.plan)
 
@@ -278,49 +245,39 @@ class DMoESimulator:
         return self._placed(x.astype(jnp.float32 if self.cfg.dtype ==
                                      "float32" else jnp.bfloat16), 0)
 
-    def _attn_gate_step(self, stack, layer, x):
-        """Step 2 (in-situ): the layer's attention and gate.  Returns x
-        after attention, the FFN's input h and the gate scores
-        (K, N, E)."""
-        p = _layer_slice(stack, layer)
-        return self._gate(p, self._mix(p, p["attn"], x, "attention"))
-
-    def _mix(self, p, weights, x, mixer: str):
-        """RMSNorm, the mixer (attention, with no rotary positions where
-        the config has none, or Mamba) on its `weights`, the residual."""
+    def _mixer_step(self, sub, period, x, *, mixer: str, gate: bool):
+        """A sublayer's mixer, in situ: RMSNorm, the mixer (attention,
+        with no rotary positions where the config has none, or Mamba),
+        the residual.  With `gate` (step 2 of its protocol round), also
+        the FFN's norm and the router's softmax: (x, h, gates (K, N, E)).
+        A hybrid sublayer holds its mixer's weights under `mixer`, a plain
+        MoE stack under `attn`."""
+        p = _layer_slice(sub, period)
+        weights = p["mixer"] if "mixer" in p else p["attn"]
         h = L.rmsnorm(x, p["norm1"], self.cfg.norm_eps)
         if mixer == "attention":
             a, _ = A.gqa_prefill(weights, h, self.cfg, causal=True)
         else:
             a, _ = S.mamba_mix(weights, h, self.cfg)
-        return self._placed(x + a, 0)
-
-    def _gate(self, p, x):
-        """The FFN's norm and the router's softmax: (x, h, gates)."""
+        x = self._placed(x + a, 0)
+        if not gate:
+            return x
         h = L.rmsnorm(x, p["norm2"], self.cfg.norm_eps)
         logits = jnp.einsum("bsd,de->bse", h.astype(jnp.float32),
                             p["ffn"]["w_gate_router"])
         return x, h, jax.nn.softmax(logits, axis=-1)
 
-    def _mixer_step(self, sub, period, x, *, mixer: str, gate: bool):
-        """A hybrid sublayer's mixer (in situ, `_mix`); with `gate`, also
-        the FFN's norm and the gate scores (K, N, E) of its protocol
-        round."""
-        p = _layer_slice(sub, period)
-        x = self._mix(p, p["mixer"], x, mixer)
-        return self._gate(p, x) if gate else x
-
     def _dense_ffn_step(self, sub, period, x):
-        """A hybrid sublayer's dense SwiGLU, in situ at the query's node."""
+        """A dense sublayer's SwiGLU, in situ at the query's node."""
         p = _layer_slice(sub, period)
         h = L.rmsnorm(x, p["norm2"], self.cfg.norm_eps)
         return self._placed(x + L.swiglu(p["ffn"], h), 0)
 
-    def _ffn_step(self, stack, layer, h):
+    def _ffn_step(self, sub, period, h):
         # On a node mesh every token's input reaches every chip, and
         # each chip computes its own experts' outputs.
         ye = self._expert_ffn(self._placed(h, None),
-                              _layer_slice(stack, layer))
+                              _layer_slice(sub, period))
         return self._placed(ye, 2)
 
     def _combine_step(self, x, ye, alpha, gates):
@@ -339,20 +296,8 @@ class DMoESimulator:
 
     def _expert_ffn(self, h, p):
         """Every expert's FFN output for every token: (K, N, E, d).
-
-        Dense in the expert axis and independent of alpha, so it can be
-        dispatched before the scheduler decides the selection.  With
-        ``routing_impl="fused"`` the same all-expert compute runs through
-        the Pallas `moe_expert_ffn` kernel instead of the XLA einsums
-        (every token replicated into every expert's capacity row block)."""
-        if self.routing_impl == "fused":
-            b, s, d = h.shape
-            e = p["ffn"]["w1"].shape[0]
-            from repro.kernels import ops as kops
-            xs = jnp.broadcast_to(h.reshape(1, b * s, d), (e, b * s, d))
-            ye = kops.moe_expert_ffn(xs, p["ffn"]["w1"], p["ffn"]["wu"],
-                                     p["ffn"]["w2"])
-            return ye.reshape(e, b, s, d).transpose(1, 2, 0, 3)
+        Dense in the expert axis and independent of alpha, so it is
+        dispatched before the scheduler decides the selection."""
         g1 = jnp.einsum("bsd,edf->bsef", h, p["ffn"]["w1"])
         u1 = jnp.einsum("bsd,edf->bsef", h, p["ffn"]["wu"])
         hh = jax.nn.silu(g1.astype(jnp.float32)).astype(h.dtype) * u1
@@ -400,25 +345,33 @@ class DMoESimulator:
             with TraceAnnotation("dmoe.embed"):
                 x = self._embed(self.params["embed"], tokens)
 
-            stack = self.params["stages"]["stage0"]
             rounds: List[proto.RoundAccounting] = []
             schedules: List[RoundSchedule] = []
             hist = np.zeros((self.rounds_per_pass, self.k))
-
-            def record(layer, rs, acct):
-                schedules.append(rs)
-                rounds.append(acct)
-                hist[layer] = rs.alpha.sum(axis=(0, 1)) / max(
-                    rs.alpha.sum(), 1)
-
-            if self.plan is None:
-                for layer in range(cfg.num_layers):
+            # Each plan entry's stacked weights: a hybrid period's
+            # `sub{i}`; a plain MoE stack is its own one sublayer.
+            stack = self.params["stages"]["stage0"]
+            subs = ([stack[f"sub{i}"] for i in range(len(self.plan))]
+                    if cfg.arch_type == "hybrid" else [stack])
+            for period in range(cfg.num_layers // len(self.plan)):
+                for (mixer, moe), sub in zip(self.plan, subs, strict=True):
+                    if not moe:
+                        p = np.int32(period)
+                        with TraceAnnotation("dmoe.mixer", kind=mixer):
+                            x = self._mixer(sub, p, x, mixer=mixer,
+                                            gate=False)
+                        with TraceAnnotation("dmoe.dense_ffn"):
+                            x = self._dense_ffn(sub, p, x)
+                        continue
+                    layer = len(rounds)
                     with TraceAnnotation("dmoe.round", layer=layer + 1,
                                          **{"pass": self.passes}):
-                        x, rs, acct = self._round(x, stack, rates, layer)
-                        record(layer, rs, acct)
-            else:
-                x = self._hybrid_layers(x, stack, rates, record)
+                        x, rs, acct = self._protocol_round(
+                            x, sub, period, mixer, rates, layer)
+                    schedules.append(rs)
+                    rounds.append(acct)
+                    hist[layer] = rs.alpha.sum(axis=(0, 1)) / max(
+                        rs.alpha.sum(), 1)
 
             with TraceAnnotation("dmoe.unembed"):
                 table = (self.params["embed"] if cfg.tie_embeddings
@@ -437,62 +390,34 @@ class DMoESimulator:
                     schedules=schedules,
                 )
 
-    def _round(self, x, stack, rates: np.ndarray, layer: int):
-        """One protocol round (steps 2-5) on the hidden states x:
-        returns (x after the Eq.-8 combine, the round's schedule, its
-        energy accounting)."""
-        with TraceAnnotation("dmoe.params"):
-            i = np.int32(layer)
-        # -- step 2: attention + gate (in-situ) ------------------------
-        with TraceAnnotation("dmoe.attn_gate"):
-            x, h, gates_dev = self._attn_gate(stack, i, x)   # gates (K, N, E)
-        return self._protocol_round(x, h, gates_dev, stack, i, rates, layer)
-
-    def _hybrid_layers(self, x, stack, rates: np.ndarray, record):
-        """The hybrid layer plan over every period: each sublayer's mixer
-        step, then a protocol round (MoE sublayer; `record` takes its
-        schedule and accounting) or the in-situ dense FFN step."""
-        layer = 0
-        for period in range(self.cfg.num_layers // len(self.plan)):
+    def _protocol_round(self, x, sub, period: int, mixer: str,
+                        rates: np.ndarray, layer: int):
+        """One protocol round (steps 2-5, `layer` 0-based) at a MoE
+        sublayer of `period`: returns (x after the Eq.-8 combine, the
+        round's schedule, its energy accounting)."""
+        # -- step 2: the mixer and the gate (in situ) ------------------
+        # A gated attention step keeps the plain MoE block's spans, any
+        # other mixer step is `dmoe.mixer` (docs/serving.md).
+        if mixer == "attention":
+            with TraceAnnotation("dmoe.params"):
+                p = np.int32(period)
+            span = TraceAnnotation("dmoe.attn_gate")
+        else:
             p = np.int32(period)
-            for i, (mixer, moe) in enumerate(self.plan):
-                sub = stack[f"sub{i}"]
-                if not moe:
-                    with TraceAnnotation("dmoe.mixer", kind=mixer):
-                        x = self._mixer(sub, p, x, mixer=mixer, gate=False)
-                    with TraceAnnotation("dmoe.dense_ffn"):
-                        x = self._dense_ffn(sub, p, x)
-                    continue
-                with TraceAnnotation("dmoe.round", layer=layer + 1,
-                                     **{"pass": self.passes}):
-                    with TraceAnnotation("dmoe.mixer", kind=mixer):
-                        x, h, gates_dev = self._mixer(sub, p, x, mixer=mixer,
-                                                      gate=True)
-                    x, rs, acct = self._protocol_round(x, h, gates_dev, sub,
-                                                       p, rates, layer)
-                record(layer, rs, acct)
-                layer += 1
-        return x
-
-    def _protocol_round(self, x, h, gates_dev, stack, i, rates: np.ndarray,
-                        layer: int):
-        """Steps 3-5 of round `layer` (0-based) once the gate scores are
-        dispatched: the expert FFNs read `stack` at index `i`."""
+            span = TraceAnnotation("dmoe.mixer", kind=mixer)
+        with span:
+            x, h, gates_dev = self._mixer(sub, p, x, mixer=mixer, gate=True)
         # -- step 3: joint expert & subcarrier allocation --------------
         # The per-expert FFN outputs don't depend on alpha (selection
-        # only weights the Eq.-8 combine), so the overlap-aware loop
-        # dispatches them BEFORE blocking on the host scheduler: the
-        # device einsums run concurrently with the host B&B.
-        if self.overlap:
-            with TraceAnnotation("dmoe.expert_ffn"):
-                ye = self._ffn(stack, i, h)
+        # only weights the Eq.-8 combine), so they are dispatched BEFORE
+        # blocking on the host scheduler: the device einsums run
+        # concurrently with the host B&B.
+        with TraceAnnotation("dmoe.expert_ffn"):
+            ye = self._ffn(sub, p, h)
         with TraceAnnotation("dmoe.gate_d2h"):
             gates = np.asarray(gates_dev, dtype=np.float64)
         with TraceAnnotation("dmoe.schedule"):
             rs = self._schedule(gates, rates, layer)
-        if not self.overlap:
-            with TraceAnnotation("dmoe.expert_ffn"):
-                ye = self._ffn(stack, i, h)
         alpha, beta = rs.alpha, rs.beta
 
         # -- steps 4-5: forward tx + FFN + backward tx + aggregate -----

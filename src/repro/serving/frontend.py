@@ -234,12 +234,6 @@ class FrontendConfig:
     # stays the reference.  Only DES-family policies with a
     # `warm_cache` attribute participate; others serve unchanged.
     warm_start: bool = False
-    # --- MoE token-dispatch backend (sim mode) --------------------
-    # Forwarded to `DMoESimulator.routing_impl`: "xla" keeps the dense
-    # einsums bit for bit, "fused" runs the expert FFNs through the
-    # Pallas kernel (`repro.kernels.moe_route` family).  Pool mode has
-    # no token-level model — any non-"xla" value is rejected there.
-    routing_impl: str = "xla"
 
 
 # ----------------------------------------------------------------------
@@ -278,19 +272,6 @@ class ServingFrontend:
         self.mode = "pool" if pool is not None else "sim"
         self.pool = pool
         self.sim = sim
-        from repro.kernels.moe_route import check_routing_impl
-        check_routing_impl(cfg.routing_impl)
-        if self.mode == "sim" and cfg.routing_impl != "xla":
-            # the simulator owns the model: thread the dispatch backend
-            # through to its expert-FFN compute (its setter rejects
-            # "grouped" — the protocol's dense all-expert FFN has no
-            # ragged token→expert assignment)
-            sim.routing_impl = cfg.routing_impl
-        elif self.mode == "pool" and cfg.routing_impl != "xla":
-            raise ValueError(
-                "routing_impl applies to the model-exact sim tier; pool "
-                "mode schedules gate scores only (no token dispatch) — "
-                f"got routing_impl={cfg.routing_impl!r}")
         if self.mode == "pool":
             if policy is None:
                 raise ValueError("pool mode needs a scheduler policy")

@@ -1,7 +1,9 @@
 """The protocol round's compiled steps (`DMoESimulator`): each jitted
 step traces once per shape, the served logits are the model's own math
-layer by layer, and `overlap` reorders dispatch without changing a bit."""
+layer by layer, one layer plan serves the plain MoE block and the Jamba
+hybrid, and the expert FFN is dispatched before the scheduler."""
 
+import hashlib
 import warnings
 
 import jax
@@ -13,9 +15,8 @@ from repro.analysis.sanitizers import recompile_guard
 from repro.configs.base import get_smoke_config
 from repro.schedulers import SchedulerPolicy, get_policy
 from repro.serving import DMoESimulator
-from repro.serving.frontend import FrontendConfig, ServingFrontend
 
-STEPS = ("_embed_step", "_attn_gate_step", "_ffn_step", "_combine_step",
+STEPS = ("_embed_step", "_mixer_step", "_ffn_step", "_combine_step",
          "_unembed_step")
 
 
@@ -36,18 +37,25 @@ class Recording(SchedulerPolicy):
         return self.inner.schedule(ctx)
 
 
-def _pass_compiles(log_dir):
-    """The `compiles` metadata of each `dmoe.pass` span, in time order."""
+def _spans(log_dir):
+    """Every `dmoe.*` span of the trace in `log_dir`: (name, start_ns,
+    end_ns, metadata), in time order."""
     path = sorted(log_dir.glob("plugins/profile/*/*.xplane.pb"))[-1]
-    passes = []
+    spans = []
     with warnings.catch_warnings():
         # jaxlib's stats type warns of its own missing __module__.
         warnings.simplefilter("ignore", DeprecationWarning)
         for plane in ProfileData.from_file(str(path)).planes:
             for line in plane.lines:
-                passes += [(ev.start_ns, dict(ev.stats)["compiles"])
-                           for ev in line.events if ev.name == "dmoe.pass"]
-    return [c for _, c in sorted(passes)]
+                spans += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                           dict(ev.stats)) for ev in line.events
+                          if ev.name.startswith("dmoe.")]
+    return sorted(spans, key=lambda s: s[1])
+
+
+def _pass_compiles(log_dir):
+    """The `compiles` metadata of each `dmoe.pass` span, in time order."""
+    return [s[3]["compiles"] for s in _spans(log_dir) if s[0] == "dmoe.pass"]
 
 
 def test_steps_trace_once_per_shape(cfg, tmp_path):
@@ -149,48 +157,75 @@ def test_served_logits_match_layerwise_reference(cfg):
         np.testing.assert_allclose(ctx.gate_scores, g, rtol=1e-4, atol=1e-6)
 
 
-@pytest.mark.parametrize("impl", ["xla", "fused"])
-def test_overlap_reorders_dispatch_only(cfg, impl):
-    tokens = np.random.default_rng(5).integers(0, cfg.vocab_size, (4, 6))
-    got = {}
-    for overlap in (True, False):
-        sim = DMoESimulator(cfg, scheme="jesa", seed=6, overlap=overlap,
-                            routing_impl=impl)
-        got[overlap] = sim.serve(tokens)
-    on, off = got[True], got[False]
-    assert np.array_equal(on.logits, off.logits)
-    assert on.summary == off.summary
-    for a, b in zip(on.schedules, off.schedules, strict=True):
-        assert np.array_equal(a.alpha, b.alpha)
-        assert np.array_equal(a.beta, b.beta)
-        assert a.energy == b.energy
+#: Each served config's layer plan: (mixer kind, protocol round?) per
+#: sublayer of a period.  A plain MoE block is a period of one.
+PLANS = {
+    "mixtral": [("attention", True)],
+    "jamba": [("mamba", False), ("mamba", True), ("mamba", False),
+              ("mamba", True), ("attention", False), ("mamba", True),
+              ("mamba", False), ("mamba", True)],
+}
+
+#: SHA-256 of one traced pass (`_digest`) per config, recorded on an
+#: 8-core CPU host before the two configs shared one layer loop.  XLA's
+#: CPU dots split their sums over the host's threads, so a host with
+#: another core count computes other last bits of the logits.
+DIGESTS = {
+    "mixtral":
+        "3db5c21a29b5900ee3d0bac2d546f19e9f913f2af69e2174f714f2428115f845",
+    "jamba":
+        "647c50dc0a042952e4f6596ce4bebc7ebf53116b374d02580363d71f83640280",
+}
 
 
-def test_routing_impl_set_after_construction_retraces(cfg):
-    """`ServingFrontend` sets the backend on a built simulator: the FFN
-    step then traces the new backend instead of reusing the old one."""
-    tokens = np.random.default_rng(7).integers(0, cfg.vocab_size, (4, 6))
-    fused = DMoESimulator(cfg, scheme="jesa", seed=8, routing_impl="fused")
-    fused.serve(tokens)
-    want = fused.serve(tokens).logits
-    sim = DMoESimulator(cfg, scheme="jesa", seed=8)
-    sim.serve(tokens)
-    sim.routing_impl = "fused"
-    before = sim.compiles
-    assert np.array_equal(sim.serve(tokens).logits, want)
-    assert sim.compiles == before + 1          # the FFN step alone
-    with pytest.raises(ValueError, match="routing_impl"):
-        sim.routing_impl = "grouped"
+@pytest.fixture(scope="module", params=sorted(PLANS))
+def traced_pass(request, cfg, tmp_path_factory):
+    """One pass of each config, served under the profiler: (name, the
+    simulator, its result, its `dmoe.*` spans)."""
+    name = request.param
+    c = cfg if name == "mixtral" else get_smoke_config("jamba2-mini")
+    sim = DMoESimulator(c, scheme="jesa", seed=11)
+    tokens = np.random.default_rng(12).integers(0, c.vocab_size, (sim.k, 8))
+    log_dir = tmp_path_factory.mktemp(name)
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        res = sim.serve(tokens)
+    finally:
+        jax.profiler.stop_trace()
+    return name, sim, res, _spans(log_dir)
 
 
-@pytest.mark.parametrize("impl", ["fused", "grouped"])
-def test_frontend_threads_routing_impl_into_sim(cfg, impl):
-    sim = DMoESimulator(cfg, scheme="jesa", seed=9)
-    config = FrontendConfig(routing_impl=impl)
-    if impl == "grouped":
-        with pytest.raises(ValueError, match="routing_impl"):
-            ServingFrontend(sim=sim, cfg=config)
-        assert sim.routing_impl == "xla"
-    else:
-        ServingFrontend(sim=sim, cfg=config)
-        assert sim.routing_impl == "fused"
+def test_plan_sets_the_rounds(traced_pass):
+    name, sim, res, _ = traced_pass
+    assert sim.plan == PLANS[name]
+    assert sim.rounds_per_pass == len(res.schedules)
+    assert sim.rounds_per_pass == res.selection_hist.shape[0]
+
+
+def test_expert_ffn_dispatched_before_gate_wait(traced_pass):
+    """The alpha-independent expert FFN step is dispatched before the
+    round blocks on its gate scores and runs the scheduler."""
+    _, sim, _, spans = traced_pass
+    rounds = [s for s in spans if s[0] == "dmoe.round"]
+    assert len(rounds) == sim.rounds_per_pass
+    for r in rounds:
+        inside = {s[0]: s[1] for s in spans
+                  if r[1] <= s[1] and s[2] <= r[2] and s is not r}
+        assert inside["dmoe.expert_ffn"] < inside["dmoe.gate_d2h"]
+        assert inside["dmoe.gate_d2h"] < inside["dmoe.schedule"]
+
+
+def _digest(res) -> str:
+    """SHA-256 of a pass's logits and every round's alpha, beta and B&B
+    node count."""
+    h = hashlib.sha256(np.ascontiguousarray(res.logits).tobytes())
+    for rs in res.schedules:
+        h.update(np.ascontiguousarray(rs.alpha).tobytes())
+        h.update(np.ascontiguousarray(rs.beta).tobytes())
+        h.update(str(int(rs.des_nodes)).encode())
+    return h.hexdigest()
+
+
+def test_pass_digest(traced_pass):
+    name, _, res, _ = traced_pass
+    assert _digest(res) == DIGESTS[name]
