@@ -45,9 +45,10 @@ node), each chip holds K / chips nodes -- their queries' hidden states
 and a replica of the shared blocks.  XLA's partitioner makes the
 exchange: every token's FFN input reaches every chip that holds experts
 (the protocol's step-4 transmission) and each chip's weighted expert
-outputs return to the tokens' chips (step 5).  The scheduler stays one
-global host policy.  With no mesh, or a mesh of one device, nothing
-is placed or constrained.
+outputs return to the tokens' chips (step 5).  The logits are gathered
+on the device after the unembed, so the host copies one buffer.  The
+scheduler stays one global host policy.  With no mesh, or a mesh of
+one device, nothing is placed or constrained.
 
 Overlap-aware round: the expert FFN einsums are dense in the expert
 axis and independent of the selection alpha (alpha only weights the
@@ -292,7 +293,15 @@ class DMoESimulator:
         return x + self._placed(y, 0).astype(x.dtype)
 
     def _unembed_step(self, norm, table, x):
-        return L.unembed(L.rmsnorm(x, norm, self.cfg.norm_eps), table)
+        """The final norm and the float32 logits.  On a node mesh each
+        chip computes its own queries' logits, which are then gathered
+        on the device: the host lands one replicated buffer, where a
+        split array would land shard by shard into a fresh host array.
+        The K split is constrained first: with the replicated one alone
+        XLA gathers the hidden state and every chip computes every
+        query's logits."""
+        logits = L.unembed(L.rmsnorm(x, norm, self.cfg.norm_eps), table)
+        return self._placed(self._placed(logits, 0), None)
 
     def _expert_ffn(self, h, p):
         """Every expert's FFN output for every token: (K, N, E, d).
@@ -381,7 +390,10 @@ class DMoESimulator:
                               chips=self.chips,
                               xchip_bytes=self.xchip_bytes(k, n))
             summary = proto.summarize(rounds)
-            with TraceAnnotation("dmoe.logits_d2h"):
+            with TraceAnnotation(
+                    "dmoe.logits_d2h", bytes=logits.nbytes,
+                    buffers=(1 if logits.is_fully_replicated
+                             else len(logits.addressable_shards))):
                 return SimResult(
                     logits=np.asarray(logits, dtype=np.float32),
                     rounds=rounds,

@@ -128,12 +128,48 @@ def test_engine_jamba_matches_reference(cfg):
 
 
 _MESH_SCRIPT = r"""
-import json, sys
+import json, re, sys, tempfile, warnings
+from pathlib import Path
 import numpy as np
 from repro.configs.base import get_smoke_config
+from repro.models import layers as L
 from repro.serving import DMoESimulator
 from repro.serving.dmoe_sim import node_mesh
 import jax
+from jax.profiler import ProfileData
+
+
+def traced(sim, tokens):
+    # One traced pass: its result, the unembed step's arguments and
+    # output, the step's all-gather result shapes, and the metadata of
+    # every `dmoe.logits_d2h` span.
+    step, seen = sim._unembed, []
+
+    def keep(*args):
+        seen.append((args, step(*args)))
+        return seen[-1][1]
+
+    sim._unembed = keep
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d)
+        try:
+            res = sim.serve(tokens)
+        finally:
+            jax.profiler.stop_trace()
+        path = sorted(Path(d).glob("plugins/profile/*/*.xplane.pb"))[-1]
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore", DeprecationWarning)
+            meta = [dict(ev.stats)
+                    for plane in ProfileData.from_file(str(path)).planes
+                    for line in plane.lines for ev in line.events
+                    if ev.name == "dmoe.logits_d2h"]
+    sim._unembed = step
+    (args, out), = seen
+    hlo = step.lower(*args).compile().as_text()
+    gathers = [[int(n) for n in dims.split(",")] for dims in re.findall(
+        r"= f32\[([0-9,]+)\]\S* all-gather\(", hlo)]
+    return res, args, out, gathers, meta
+
 cfg = get_smoke_config("jamba2-mini")
 tokens = np.random.default_rng(0).integers(0, cfg.vocab_size, (4, 16))
 one = DMoESimulator(cfg, scheme="jesa", seed=3,
@@ -141,6 +177,25 @@ one = DMoESimulator(cfg, scheme="jesa", seed=3,
 four = DMoESimulator(cfg, scheme="jesa", seed=3, mesh=node_mesh())
 a, b = one.serve(tokens), four.serve(tokens)
 w1 = four.params["stages"]["stage0"]["sub1"]["ffn"]["w1"]
+c, args, out4, gathers4, meta4 = traced(four, tokens)
+d, _, out1, gathers1, meta1 = traced(one, tokens)
+# The same pass's logits left split on K, as a step without the gather
+# returns them.
+split = jax.jit(lambda n, t, x: four._placed(
+    L.unembed(L.rmsnorm(x, n, cfg.norm_eps), t), 0))(*args)
+extra = {
+    "vocab": cfg.vocab_size,
+    "replicated": [out1.is_fully_replicated, out4.is_fully_replicated],
+    "unembed_devices": [len(out1.sharding.device_set),
+                        len(out4.sharding.device_set)],
+    "gathers": [gathers1, gathers4],
+    "d2h": [meta1, meta4],
+    "split": [split.is_fully_replicated, len(split.addressable_shards)],
+    "split_equal": bool(np.array_equal(np.asarray(split), c.logits)),
+    "logits": [str(c.logits.dtype), list(c.logits.shape)],
+    "fresh": not (np.shares_memory(c.logits, b.logits)
+                  or np.shares_memory(d.logits, a.logits)),
+}
 print(json.dumps({
     "devices": len(jax.devices()), "chips": [one.chips, four.chips],
     "xchip": [one.xchip_bytes(4, 16), four.xchip_bytes(4, 16)],
@@ -151,7 +206,8 @@ print(json.dumps({
                      and x.energy == y.energy
                      for x, y in zip(a.schedules, b.schedules, strict=True)),
     "logit_err": float(np.abs(a.logits - b.logits).max()
-                       / np.abs(a.logits).max())}))
+                       / np.abs(a.logits).max()),
+    **extra}))
 """
 
 
@@ -185,3 +241,33 @@ def test_xchip_bytes_from_shapes(cfg, mesh_run):
     3 other devices and the 3 other devices' float32 sums back."""
     d = cfg.d_model
     assert mesh_run["xchip"] == [0, 4 * 3 * 4 * 16 * d * (4 + 4)]
+
+
+def test_mesh_logits_land_from_one_buffer(mesh_run):
+    """On the 4-device mesh the unembed step computes its logits split on
+    K and gathers them on the device (the gathered shape is the logits',
+    not the hidden state's), so the host copies one buffer of
+    K·N·V·4 bytes; on one device it copies one buffer too."""
+    v = mesh_run["vocab"]
+    assert mesh_run["replicated"] == [True, True]
+    assert mesh_run["gathers"][1] == [[4, 16, v]]
+    want = {"buffers": 1, "bytes": 4 * 16 * v * 4}
+    for meta in mesh_run["d2h"]:
+        assert [{k: m[k] for k in want} for m in meta] == [want]
+
+
+def test_mesh_logits_equal_the_split_fetch(mesh_run):
+    """The gathered logits equal, bit for bit, the same pass's logits
+    fetched split on K from 4 buffers, and every pass lands a fresh
+    float32 (K, N, V) array."""
+    assert mesh_run["split"] == [False, 4]
+    assert mesh_run["split_equal"] is True
+    assert mesh_run["logits"] == ["float32", [4, 16, mesh_run["vocab"]]]
+    assert mesh_run["fresh"] is True
+
+
+def test_one_device_mesh_keeps_the_unembed_step(mesh_run):
+    """A mesh of one device places and constrains nothing: the unembed
+    step's output lives on that device and its HLO gathers nothing."""
+    assert mesh_run["unembed_devices"] == [1, 4]
+    assert mesh_run["gathers"][0] == []

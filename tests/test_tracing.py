@@ -120,6 +120,10 @@ def test_served_pass_spans(cfg, scheme, tmp_path):
 
     passes = [s for s, _ in spans if s[0] == "dmoe.pass"]
     assert [p[3]["pass"] for p in passes] == [1, 2]
+    # The logits' copy: one device buffer of K·N·V float32 a pass.
+    k, n = waves[0].shape
+    assert [s[3] for s, _ in spans if s[0] == "dmoe.logits_d2h"] == [
+        {"buffers": 1, "bytes": k * n * cfg.vocab_size * 4}] * 2
     for p in passes:
         rounds = sorted((s for s, line in spans if s[0] == "dmoe.round"
                          and _parent(s, line) == p), key=lambda s: s[1])
@@ -161,7 +165,8 @@ def test_hybrid_pass_spans(tmp_path):
     """The Jamba plan's spans: `dmoe.mixer` (kind mamba or attention) and
     `dmoe.dense_ffn` at every sublayer that is no protocol round, the
     mixer inside `dmoe.round` where it is one; `dmoe.pass` carries the
-    chips and the bytes moved between them (none on one device)."""
+    chips and the bytes moved between them (none on one device), and
+    `dmoe.logits_d2h` the buffers and bytes of the logits' copy."""
     cfg = get_smoke_config("jamba2-mini")
     sim = DMoESimulator(cfg, scheme="jesa", seed=2)
     tokens = np.random.default_rng(3).integers(0, cfg.vocab_size, (4, 8))
@@ -177,6 +182,8 @@ def test_hybrid_pass_spans(tmp_path):
         by_name.setdefault(s[0], []).append(s)
     (p,) = by_name["dmoe.pass"]
     assert p[3]["chips"] == 1 and p[3]["xchip_bytes"] == 0
+    (d2h,) = by_name["dmoe.logits_d2h"]
+    assert d2h[3] == {"buffers": 1, "bytes": 4 * 8 * cfg.vocab_size * 4}
     mixers = sorted(by_name["dmoe.mixer"], key=lambda s: s[1])
     assert [m[3]["kind"] for m in mixers] == [
         "attention" if i == 4 else "mamba" for i in range(8)]
