@@ -40,6 +40,16 @@ class MoEConfig:
     qos_z: float = 1.0
     qos_gamma0: float = 0.7           # gamma^(l) = gamma0^l
     max_experts: int = 0              # D (0 -> top_k)
+    # --- device-limited routing (DeepSeek-V2 `group_limited_greedy`) ----
+    # `n_group` groups of num_experts / n_group experts, a token's top_k
+    # taken within its best `topk_group` groups.  Only the protocol
+    # simulator serves groups, each as one edge node; `models.moe`
+    # rejects them.  1 = no groups.
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0  # routed weights x this
+    norm_topk_prob: bool = True       # renormalise the top_k weights;
+                                      # groups take only False
     aux_loss_weight: float = 0.01
     router_z_weight: float = 1e-3
 
@@ -80,7 +90,16 @@ class ModelConfig:
     moe: MoEConfig = dataclasses.field(default_factory=MoEConfig)
     ssm: SSMConfig = dataclasses.field(default_factory=SSMConfig)
 
-    # MLA (DeepSeek-V3)
+    # YaRN rotary scaling (DeepSeek-V2/V3 `rope_scaling`); a factor of 1
+    # keeps plain rotary positions
+    yarn_factor: float = 1.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+
+    # MLA (DeepSeek-V2/V3)
     mla: bool = False
     q_lora_rank: int = 1536
     kv_lora_rank: int = 512

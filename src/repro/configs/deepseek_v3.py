@@ -1,6 +1,8 @@
 """deepseek-v3-671b [moe] — 61L d_model=7168 128H d_ff=2048(expert)
 vocab=129280, MoE 256 routed experts top-8 + 1 shared, MLA
-(kv_lora=512, q_lora=1536, rope_dim=64), first 3 layers dense
+(kv_lora=512, q_lora=1536, rope_dim=64) with YaRN rotary positions
+(factor 40 over 4096 original positions, beta_fast 32, beta_slow 1,
+mscale = mscale_all_dim = 1), first 3 layers dense
 (d_ff=18432), MTP depth-1 training objective (shared embedding/head +
 one extra MLA block predicting token t+2; serving unaffected).
 [arXiv:2412.19437]
@@ -25,6 +27,12 @@ CONFIG = ModelConfig(
     vocab_size=129280,
     rope_theta=1e4,
     max_seq_len=131072,
+    yarn_factor=40.0,
+    yarn_original_max_pos=4096,
+    yarn_beta_fast=32.0,
+    yarn_beta_slow=1.0,
+    yarn_mscale=1.0,
+    yarn_mscale_all_dim=1.0,
     mla=True,
     q_lora_rank=1536,
     kv_lora_rank=512,

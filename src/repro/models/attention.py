@@ -1,6 +1,6 @@
 """Attention: GQA (full / causal / sliding-window), chunked flash-style
-prefill, MLA (DeepSeek-V3) with absorbed-weight decode, cross-attention,
-and KV caches.
+prefill, MLA (DeepSeek-V2/V3, YaRN positions) with absorbed-weight
+decode, cross-attention, and KV caches.
 
 Memory discipline: prefill at 32k+ never materializes the (S, S) score
 matrix — `chunked_attention` runs an online-softmax scan over KV chunks
@@ -84,11 +84,13 @@ def _sdpa(q, k, v, bias, scale) -> jnp.ndarray:
 
 
 def chunked_attention(q, k, v, *, q_pos, kv_pos, causal: bool, window: int,
-                      q_chunk: int = 1024, kv_chunk: int = 1024) -> jnp.ndarray:
+                      q_chunk: int = 1024, kv_chunk: int = 1024,
+                      scale: Optional[float] = None) -> jnp.ndarray:
     """Online-softmax attention; never materializes (Sq, Sk).
 
     q: (B, Sq, Hkv, R, Dh); k, v: (B, Sk, Hkv, Dh);
-    q_pos: (Sq,), kv_pos: (Sk,) absolute positions.
+    q_pos: (Sq,), kv_pos: (Sk,) absolute positions; `scale` of the
+    scores (default 1/sqrt(Dh)).
     Returns (B, Sq, Hkv, R, Dh) fp32.
     """
     b, sq, hkv, r, dh = q.shape
@@ -99,7 +101,8 @@ def chunked_attention(q, k, v, *, q_pos, kv_pos, causal: bool, window: int,
     nq = -(-sq // q_chunk)
     nk = -(-sk // kv_chunk)
     pq, pk = nq * q_chunk - sq, nk * kv_chunk - sk
-    scale = 1.0 / np.sqrt(dh)
+    if scale is None:
+        scale = 1.0 / np.sqrt(dh)
 
     qf = jnp.pad(q, ((0, 0), (0, pq), (0, 0), (0, 0), (0, 0)))
     kf = jnp.pad(k, ((0, 0), (0, pk), (0, 0), (0, 0)))
@@ -247,8 +250,31 @@ def gqa_decode(params, x, cache, cfg: ModelConfig, *, window: int = 0,
 
 
 # ----------------------------------------------------------------------
-# MLA (DeepSeek-V3) — low-rank KV compression, absorbed decode
+# MLA (DeepSeek-V2/V3) — low-rank KV compression, absorbed decode
 # ----------------------------------------------------------------------
+
+def mla_rope(x, positions, cfg: ModelConfig):
+    """MLA's rotary positions on x (..., S, H, rope_dim): YaRN's where
+    the config stretches them (`yarn_factor` > 1), plain otherwise.  The
+    rotated dims are halves (rotate-half); DeepSeek's interleaved pairs
+    are a fixed permutation of the rope columns of `wq_b` and `wkv_a`."""
+    if cfg.yarn_factor <= 1:
+        return L.apply_rope(x, positions, cfg.rope_theta)
+    yarn = (cfg.yarn_factor, cfg.yarn_original_max_pos, cfg.yarn_beta_fast,
+            cfg.yarn_beta_slow)
+    magnitude = (L.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+                 / L.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+    return L.apply_rope(x, positions, cfg.rope_theta, yarn, magnitude)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(qk head dim), x mscale(factor, mscale_all_dim)^2 under
+    YaRN (DeepSeek-V2's `softmax_scale`)."""
+    scale = 1.0 / np.sqrt(cfg.nope_head_dim + cfg.rope_head_dim)
+    if cfg.yarn_factor > 1 and cfg.yarn_mscale_all_dim:
+        scale *= L.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
 
 def init_mla(key, cfg: ModelConfig, dtype) -> Dict[str, jnp.ndarray]:
     dn, dr, dv = cfg.nope_head_dim, cfg.rope_head_dim, cfg.v_head_dim
@@ -275,14 +301,13 @@ def _mla_qkv_prefill(params, x, cfg, positions):
                       params["q_norm"], cfg.norm_eps)
     q = jnp.einsum("bsr,rhe->bshe", q_lat, params["wq_b"])
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = L.apply_rope(q_rope, positions[None, :], cfg.rope_theta)
+    q_rope = mla_rope(q_rope, positions[None, :], cfg)
 
     kv_a = jnp.einsum("bsd,dr->bsr", x, params["wkv_a"])
     ckv = L.rmsnorm(kv_a[..., : cfg.kv_lora_rank], params["kv_norm"],
                     cfg.norm_eps)
     k_rope = kv_a[..., cfg.kv_lora_rank:]
-    k_rope = L.apply_rope(k_rope[:, :, None, :], positions[None, :],
-                          cfg.rope_theta)[:, :, 0]
+    k_rope = mla_rope(k_rope[:, :, None, :], positions[None, :], cfg)[:, :, 0]
     return q_nope, q_rope, ckv, k_rope
 
 
@@ -308,11 +333,13 @@ def mla_prefill(params, x, cfg: ModelConfig, *, window: int = 0,
         out = chunked_attention(qg, k, v_pad(v, k), q_pos=positions,
                                 kv_pos=positions, causal=True, window=window,
                                 q_chunk=cfg.attn_q_chunk,
-                                kv_chunk=cfg.attn_kv_chunk)
+                                kv_chunk=cfg.attn_kv_chunk,
+                                scale=mla_softmax_scale(cfg))
         out = out[..., :dv]
     else:
         bias = _mask_bias(positions, positions, causal=True, window=window)[None]
-        out = _sdpa(qg, k, v_pad(v, k), bias, 1.0 / np.sqrt(dn + dr))[..., :dv]
+        out = _sdpa(qg, k, v_pad(v, k), bias,
+                    mla_softmax_scale(cfg))[..., :dv]
     out = out.reshape(b, s, h, dv).astype(x.dtype)
     y = jnp.einsum("bshe,hed->bsd", out, params["wo"])
 
@@ -349,11 +376,11 @@ def mla_decode(params, x, cache, cfg: ModelConfig, *, window: int = 0):
                       params["q_norm"], cfg.norm_eps)
     q = jnp.einsum("bsr,rhe->bshe", q_lat, params["wq_b"])
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = L.apply_rope(q_rope, pos, cfg.rope_theta)
+    q_rope = mla_rope(q_rope, pos, cfg)
 
     kv_a = jnp.einsum("bsd,dr->bsr", x, params["wkv_a"])
     ckv_new = L.rmsnorm(kv_a[..., :dc], params["kv_norm"], cfg.norm_eps)
-    krope_new = L.apply_rope(kv_a[:, :, None, dc:], pos, cfg.rope_theta)[:, :, 0]
+    krope_new = mla_rope(kv_a[:, :, None, dc:], pos, cfg)[:, :, 0]
 
     rows = jnp.arange(b)
     ckv = cache["ckv"].at[rows, idx].set(
@@ -376,7 +403,7 @@ def mla_decode(params, x, cache, cfg: ModelConfig, *, window: int = 0):
                          preferred_element_type=jnp.float32)
               + jnp.einsum("bshe,bke->bhsk", q_rope.astype(krope.dtype),
                            krope, preferred_element_type=jnp.float32))
-    scores = scores / np.sqrt(dn + dr) + bias[:, None, None, :]
+    scores = scores * mla_softmax_scale(cfg) + bias[:, None, None, :]
     probs = jax.nn.softmax(scores, axis=-1)
     ctx_c = jnp.einsum("bhsk,bkr->bshr", probs.astype(ckv.dtype), ckv,
                        preferred_element_type=jnp.float32)

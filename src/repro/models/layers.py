@@ -7,6 +7,7 @@ sharding rules in `repro.distributed.sharding` match on key paths.
 
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -49,18 +50,55 @@ def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
 # RoPE
 # ----------------------------------------------------------------------
 
-def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                            / head_dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude scaling 0.1 * mscale * ln(factor) + 1; 1 where
+    the factor does not stretch the positions."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """Rotary embedding. x: (..., S, H, Dh); positions: broadcastable (..., S)."""
+def rope_frequencies(head_dim: int, theta: float,
+                     yarn: Optional[Tuple[float, int, float, float]] = None,
+                     ) -> jnp.ndarray:
+    """Inverse frequencies of the rotary pairs.  With `yarn` = (factor,
+    original positions, beta_fast, beta_slow), YaRN's blend (DeepSeek-V2's
+    `DeepseekV2YarnRotaryEmbedding`): pairs that turn more than beta_fast
+    times over the original positions keep their frequency, those that
+    turn fewer than beta_slow times take it / factor, and a linear ramp
+    between the two correction dims blends the rest."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                             / head_dim))
+    if yarn is None:
+        return freqs
+    factor, original, beta_fast, beta_slow = yarn
+
+    def correction_dim(rotations):
+        return (head_dim * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    inter = 1.0 / (factor * theta ** (jnp.arange(0, head_dim, 2,
+                                                  dtype=jnp.float32)
+                                      / head_dim))
+    return inter * ramp + freqs * (1.0 - ramp)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               yarn: Optional[Tuple[float, int, float, float]] = None,
+               magnitude: float = 1.0) -> jnp.ndarray:
+    """Rotary embedding. x: (..., S, H, Dh); positions: broadcastable (..., S).
+    `yarn` as `rope_frequencies` takes it; cos and sin are x `magnitude`."""
     dh = x.shape[-1]
-    freqs = rope_frequencies(dh, theta)                      # (Dh/2,)
+    freqs = rope_frequencies(dh, theta, yarn)                # (Dh/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, Dh/2)
     cos = jnp.cos(angles)[..., None, :]                      # (..., S, 1, Dh/2)
     sin = jnp.sin(angles)[..., None, :]
+    if magnitude != 1.0:
+        cos, sin = cos * magnitude, sin * magnitude
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -184,6 +184,8 @@ def moe_ffn(params, x, cfg: ModelConfig, layer_idx,
     """
     b, s, d = x.shape
     m = cfg.moe
+    assert m.n_group == 1, \
+        "device-limited routing (n_group > 1) is served by DMoESimulator only"
     e = m.num_experts
     combine, mask, aux = _router(params, x, cfg, layer_idx, expert_costs)
 

@@ -157,13 +157,15 @@ def test_served_logits_match_layerwise_reference(cfg):
         np.testing.assert_allclose(ctx.gate_scores, g, rtol=1e-4, atol=1e-6)
 
 
-#: Each served config's layer plan: (mixer kind, protocol round?) per
-#: sublayer of a period.  A plain MoE block is a period of one.
+#: Each served config's layer plan: per stage, its params key, the
+#: (mixer kind, protocol round?) of each sublayer of its period, and its
+#: periods.  A plain MoE block is a period of one.
 PLANS = {
-    "mixtral": [("attention", True)],
-    "jamba": [("mamba", False), ("mamba", True), ("mamba", False),
-              ("mamba", True), ("attention", False), ("mamba", True),
-              ("mamba", False), ("mamba", True)],
+    "mixtral": [("stage0", [("attention", True)], 2)],
+    "jamba": [("stage0", [("mamba", False), ("mamba", True),
+                          ("mamba", False), ("mamba", True),
+                          ("attention", False), ("mamba", True),
+                          ("mamba", False), ("mamba", True)], 1)],
 }
 
 #: SHA-256 of one traced pass (`_digest`) per config, recorded on an

@@ -63,10 +63,10 @@ def served(cfg):
 
 def test_plan_is_published_period(cfg):
     sim = DMoESimulator(cfg, scheme="topk", seed=0)
-    assert sim.plan == [("mamba", False), ("mamba", True),
-                        ("mamba", False), ("mamba", True),
-                        ("attention", False), ("mamba", True),
-                        ("mamba", False), ("mamba", True)]
+    assert sim.plan == [("stage0", [("mamba", False), ("mamba", True),
+                                    ("mamba", False), ("mamba", True),
+                                    ("attention", False), ("mamba", True),
+                                    ("mamba", False), ("mamba", True)], 1)]
     assert sim.rounds_per_pass == 4 and sim.chips == 1
 
 

@@ -82,6 +82,21 @@ def test_decode_consistency_mla_moe():
                       capacity_factor=8.0)))
 
 
+def test_decode_consistency_mla_yarn():
+    """MLA with DeepSeek-V2's YaRN positions and softmax scale: the
+    engine's cached decode applies both as its full prefill does."""
+    _consistency(ModelConfig(
+        arch_type="moe", num_layers=3, d_model=64, num_heads=4,
+        num_kv_heads=4, vocab_size=128, dtype="float32",
+        param_dtype="float32", mla=True, q_lora_rank=32, kv_lora_rank=16,
+        rope_head_dim=8, nope_head_dim=16, v_head_dim=16, rope_theta=1e4,
+        yarn_factor=40.0, yarn_original_max_pos=4096, yarn_beta_fast=32.0,
+        yarn_beta_slow=1.0, yarn_mscale=0.707, yarn_mscale_all_dim=0.707,
+        moe=MoEConfig(num_experts=4, top_k=2, d_ff_expert=64,
+                      num_shared_experts=2, first_dense_layers=1,
+                      capacity_factor=8.0)))
+
+
 def test_decode_consistency_rwkv():
     _consistency(ModelConfig(
         arch_type="ssm", num_layers=2, d_model=64, vocab_size=128,
@@ -148,6 +163,19 @@ def test_moe_qos_constraint_satisfied():
     assert float(a["experts_per_token"]) <= 4.0 + 1e-6
     # layer-mean QoS: gamma0=0.5 -> thresholds 0.5, 0.25 -> mean 0.375
     assert float(a["selected_gate_mass"]) >= 0.3
+
+
+def test_moe_rejects_node_groups():
+    """Device-limited routing (`n_group` > 1) is the protocol
+    simulator's alone: the engine's MoE layer refuses it rather than
+    route such a config as plain top-k."""
+    from repro.configs.base import get_smoke_config
+
+    cfg = get_smoke_config("deepseek-v2")
+    params = Model(cfg).init(jax.random.PRNGKey(0))
+    toks = jnp.zeros((1, 4), jnp.int32)
+    with pytest.raises(AssertionError, match="n_group"):
+        forward(params, {"tokens": toks}, cfg, mode="full")
 
 
 def test_moe_capacity_drops_reported():

@@ -198,3 +198,38 @@ def test_hybrid_pass_spans(tmp_path):
                  "dmoe.combine", "dmoe.account"):
         assert len(by_name[name]) == 4
         assert all(_parent(s, line)[0] == "dmoe.round" for s in by_name[name])
+
+
+def test_group_pass_spans(tmp_path):
+    """DeepSeek-V2's plan over node groups: the leading dense layer's
+    `dmoe.mixer` (kind mla) and `dmoe.dense_ffn` in `dmoe.pass`, each MoE
+    round's gated `dmoe.mixer` of kind mla; `dmoe.expert_ffn` carries the
+    held routed experts and the shared experts, `dmoe.pass` the pairs
+    the combines weighed from the held experts, as the result counts
+    them."""
+    cfg = get_smoke_config("deepseek-v2")
+    sim = DMoESimulator(cfg, scheme="jesa", seed=2, held_nodes=[1, 2])
+    tokens = np.random.default_rng(3).integers(0, cfg.vocab_size, (4, 8))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        res = sim.serve(tokens)
+    finally:
+        jax.profiler.stop_trace()
+    (line,) = [line for line in _span_lines(tmp_path)
+               if any(s[0] == "dmoe.pass" for s in line)]
+    by_name = {}
+    for s in line:
+        by_name.setdefault(s[0], []).append(s)
+    (p,) = by_name["dmoe.pass"]
+    held = sum(int(((c >= 4) & (c < 12)).sum()) for c in res.routed)
+    assert p[3]["routed_pairs"] == res.routed_pairs == held
+    assert 0 < held < sum(c.size for c in res.routed)
+    mixers = sorted(by_name["dmoe.mixer"], key=lambda s: s[1])
+    assert [m[3]["kind"] for m in mixers] == ["mla"] * 3
+    assert [_parent(m, line)[0] for m in mixers] == [
+        "dmoe.pass", "dmoe.round", "dmoe.round"]
+    (dense,) = by_name["dmoe.dense_ffn"]
+    assert _parent(dense, line)[0] == "dmoe.pass"
+    ffn = by_name["dmoe.expert_ffn"]
+    assert [s[3] for s in ffn] == [{"held": 8, "shared": 2}] * 2
+    assert "dmoe.attn_gate" not in by_name and "dmoe.params" not in by_name
